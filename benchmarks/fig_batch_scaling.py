@@ -45,8 +45,14 @@ every row's matmuls), against a per-shard cost floor set by the Φ stream
 each shard re-reads (sharding de-amortizes the batch's operator traffic —
 the paper's bandwidth law cuts both ways).
 
-A final single-device stage compares **scheduling policies** on the bursty
-single-request trace of ``serve-continuous`` (``repro.parallel.scheduler``):
+The device-count sweep is a CPU construct: it needs a child process per
+forced host-device count, and on an accelerator the parent holds the chip the
+children would need. On a non-CPU backend the sweep is refused with a message
+and only the scheduling stage below runs.
+
+A final single-device stage, in this process, compares **scheduling
+policies** on the bursty single-request trace of ``serve-continuous``
+(``repro.parallel.scheduler``):
 ``continuous`` (mid-flight slot refill) vs ``lockstep`` (full-table drains —
 the chunked baseline in the same engine, same executable, same request set).
 Rows carry p50/p99 request latency, items/sec, slot occupancy, and the
@@ -163,9 +169,9 @@ def worker(ndev: int, fast: bool) -> None:
         print("ROW " + json.dumps(row), flush=True)
 
 
-def sched_worker(fast: bool) -> None:
+def sched_rows(fast: bool) -> list[dict]:
     """Continuous vs lockstep scheduling on the bursty request trace
-    (:mod:`repro.parallel.scheduler`), single process.
+    (:mod:`repro.parallel.scheduler`), on this process's default device.
 
     Both policies run the SAME engine, executable, and request set — only the
     refill rule differs — so the items/sec ratio isolates the scheduling
@@ -175,7 +181,7 @@ def sched_worker(fast: bool) -> None:
     (rel error means) must match across policies: continuous reorders *when*
     rows run, never *what* they compute (every answer is bitwise its
     standalone solve — pinned by tests/test_scheduler.py and the ``sched`` CI
-    tier, so this worker spends its wall on throughput, not re-verification).
+    tier, so this stage spends its wall on throughput, not re-verification).
     """
     import dataclasses
 
@@ -184,11 +190,12 @@ def sched_worker(fast: bool) -> None:
 
     cfg = (dataclasses.replace(CONTINUOUS, m=128, n=256, s=16, n_requests=48)
            if fast else CONTINUOUS)
+    rows = []
     for policy in ("lockstep", "continuous"):
         serve_scheduled(cfg, policy)  # warm: trace + compile the segment step
         out = max((serve_scheduled(cfg, policy) for _ in range(3)),
                   key=lambda o: o["items_per_s"])  # best-of-N, timeit-style
-        row = {
+        rows.append({
             "name": f"batch/continuous_sched_{policy}", "devices": 1,
             "wall_ms": round(out["wall_s"] * 1e3, 1),
             **{k: out[k] for k in (
@@ -197,13 +204,18 @@ def sched_worker(fast: bool) -> None:
                 "latency_p50_s", "latency_p99_s", "queue_wait_ticks_mean",
                 "iters_used_mean", "rel_error_easy_mean",
                 "rel_error_hard_mean")},
-        }
-        print("ROW " + json.dumps(row), flush=True)
+        })
+    lock = next(r for r in rows if r["scheduler"] == "lockstep")
+    for r in rows:
+        if r["scheduler"] == "continuous":
+            r["speedup_vs_lockstep"] = round(
+                r["items_per_s"] / lock["items_per_s"], 2)
+    return rows
 
 
-def run(fast: bool = True):
-    """Parent: one subprocess per device count (XLA_FLAGS is read once, at
-    backend init, so each count needs a fresh process). Yields CSV rows."""
+def _device_sweep(fast: bool) -> list[dict]:
+    """One subprocess per forced host-device count (XLA_FLAGS is read once, at
+    backend init, so each count needs a fresh process)."""
     from repro.parallel.batch import force_host_devices
 
     here = os.path.dirname(os.path.abspath(__file__))
@@ -223,34 +235,33 @@ def run(fast: bool = True):
         for line in res.stdout.splitlines():
             if line.startswith("ROW "):
                 records.append(json.loads(line[4:]))
+    return records
 
-    # scheduling-policy comparison: continuous vs lockstep refill on the
-    # bursty heterogeneous request trace (fresh subprocess: single device)
-    env = dict(os.environ)
-    env["PYTHONPATH"] = "src" + os.pathsep + env.get("PYTHONPATH", "")
-    cmd = [sys.executable, os.path.join(here, "fig_batch_scaling.py"),
-           "--sched-worker"] + (["--fast"] if fast else [])
-    res = subprocess.run(cmd, env=env, cwd=root, capture_output=True,
-                         text=True, timeout=1800)
-    if res.returncode != 0:
-        raise RuntimeError(
-            f"scheduling worker failed:\n{res.stderr[-2000:]}")
-    sched_rows = [json.loads(line[4:]) for line in res.stdout.splitlines()
-                  if line.startswith("ROW ")]
-    lock = next(r for r in sched_rows if r["scheduler"] == "lockstep")
-    for r in sched_rows:
-        if r["scheduler"] == "continuous":
-            r["speedup_vs_lockstep"] = round(
-                r["items_per_s"] / lock["items_per_s"], 2)
-    records.extend(sched_rows)
 
-    base = next(r for r in records if r["name"].endswith("singledev_baseline"))
+def run(fast: bool = True):
+    """Device-count sweep (CPU only), then the in-process scheduling stage.
+    Yields CSV rows."""
+    import jax
+
+    backend = jax.default_backend()
+    if backend == "cpu":
+        records = _device_sweep(fast)
+    else:
+        print(f"# batch-scaling: device-count sweep refused on {backend!r}: it "
+              "forces host (CPU) devices in child processes, and this process "
+              "holds the accelerator they would need; run the sweep under "
+              "JAX_PLATFORMS=cpu", flush=True)
+        records = []
+    records.extend(sched_rows(fast))
+
+    base = next((r for r in records if r["name"].endswith("singledev_baseline")),
+                None)
     out_rows = []
     for r in records:
         # the artifact must self-describe its hardware: forced host devices
         # timeshare the physical cores, which cap fixed-work scaling
         r["host_cores"] = os.cpu_count()
-        if "gaussian" in r["name"] and "sharded" in r["name"]:
+        if base and "gaussian" in r["name"] and "sharded" in r["name"]:
             r["speedup_vs_single_device"] = round(
                 r["items_per_s"] / base["items_per_s"], 2)
         derived = " ".join(f"{k}={v}" for k, v in r.items()
@@ -267,8 +278,6 @@ if __name__ == "__main__":
     if "--worker" in sys.argv:
         i = sys.argv.index("--worker")
         worker(int(sys.argv[i + 1]), "--fast" in sys.argv)
-    elif "--sched-worker" in sys.argv:
-        sched_worker("--fast" in sys.argv)
     else:
         for row in run(fast="--full" not in sys.argv):
             print(row)

@@ -32,6 +32,9 @@ def main(argv=None) -> None:
                     help="also write all rows as JSON to PATH")
     args = ap.parse_args(argv)
 
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     from benchmarks import (
         fig1_sky,
         fig3_error_coeffs,

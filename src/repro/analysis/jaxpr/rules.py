@@ -1,4 +1,5 @@
-"""IR rules JX101–JX106 over traced jaxprs and operator contracts.
+"""IR rules JX101, JX102 and JX104–JX106 over traced jaxprs and operator
+contracts.
 
 These see what the AST tier structurally cannot: the jaxpr is the graph XLA
 actually compiles, *after* Python-level indirection (``make_iteration_
@@ -22,8 +23,6 @@ JAXPR_RULE_SUMMARIES = {
              "c128/f64/f32 anywhere in the traced call graph",
     "JX102": "recompile risk: weak_type leaking into entry outputs; "
              "primitive skeleton diverging between two abstract shapes",
-    "JX103": "dead loop carry: while/scan carry component passed through "
-             "unchanged and never read — dead bytes every iteration",
     "JX104": "host transfer in hot loop: callback/infeed/outfeed/device_put "
              "primitives inside a while/scan body",
     "JX105": "baked constant: array constant above threshold bytes closed "
@@ -36,7 +35,8 @@ JAXPR_RULE_SUMMARIES = {
 #: row is ~128 B at serving widths; a baked Φ is tens of KB even at toy shapes.
 CONST_THRESHOLD_BYTES = 4096
 
-_HOT_TRANSFER_PRIMS = {"infeed", "outfeed", "device_put", "copy_to_host_async"}
+_HOT_TRANSFER_PRIMS = {"infeed", "outfeed", "device_put", "copy_to_host_async",
+                       "debug_print"}
 
 
 @dataclasses.dataclass
@@ -191,78 +191,6 @@ def check_jx102_recompile(name, closed, alt_closed):
 
 
 # --------------------------------------------------------------------------
-# JX103 — dead while/scan carry components
-
-
-def _carry_views(eqn):
-    """(body_jaxpr, carry_invars, carry_outvars, extra_reader_jaxprs)."""
-    p = eqn.params
-    if eqn.primitive.name == "while":
-        body = p["body_jaxpr"].jaxpr
-        nc = p["body_nconsts"]
-        cond = p["cond_jaxpr"].jaxpr
-        cond_carry = cond.invars[p["cond_nconsts"]:]
-        return body, body.invars[nc:], body.outvars, [(cond, cond_carry)]
-    if eqn.primitive.name == "scan":
-        body = p["jaxpr"].jaxpr
-        nc, ncar = p["num_consts"], p["num_carry"]
-        return body, body.invars[nc:nc + ncar], body.outvars[:ncar], []
-    return None
-
-
-def _reads(jaxpr):
-    """Vars read anywhere in ``jaxpr``: eqn inputs + jaxpr outputs."""
-    used = set()
-    for eqn in jaxpr.eqns:
-        for v in eqn.invars:
-            if hasattr(v, "aval") and not _is_literal(v):
-                used.add(id(v))
-    return used
-
-
-def _is_literal(v) -> bool:
-    return type(v).__name__ == "Literal"
-
-
-def check_jx103_dead_carry(name, closed):
-    import numpy as np
-
-    out = []
-    for eqn, _ in iter_eqns(closed.jaxpr):
-        views = _carry_views(eqn)
-        if views is None:
-            continue
-        body, c_in, c_out, extra = views
-        body_reads = _reads(body)
-        for i, (vin, vout) in enumerate(zip(c_in, c_out)):
-            if _is_literal(vout) or vout is not vin:
-                continue  # rewritten each iteration — live
-            if id(vin) in body_reads:
-                continue
-            # passthrough position read by another output slot → live
-            if any(o is vin for j, o in enumerate(body.outvars) if j != i
-                   and not _is_literal(o)):
-                continue
-            if any(id(extra_carry[i]) in _reads(sub)
-                   or any(o is extra_carry[i] for o in sub.outvars)
-                   for sub, extra_carry in extra if i < len(extra_carry)):
-                continue
-            aval = vin.aval
-            nbytes = int(np.prod(aval.shape, dtype=np.int64)) * \
-                np.dtype(aval.dtype).itemsize
-            out.append(Issue(
-                "JX103",
-                f"`{name}`: {eqn.primitive.name} carry[{i}] "
-                f"({np.dtype(aval.dtype).name}{list(aval.shape)}) is dead — "
-                f"passed through unchanged and never read, hauling "
-                f"{nbytes} B through every iteration",
-                f"{name} :: {eqn.primitive.name} carry[{i}] "
-                f"{np.dtype(aval.dtype).name}{list(aval.shape)}",
-                site=eqn_site(eqn)))
-    return out
-
-
-# --------------------------------------------------------------------------
 # JX104 — host↔device traffic inside the hot loop
 
 
@@ -298,11 +226,14 @@ def check_jx105_baked_const(name, closed,
             if id(const) in seen:
                 continue
             seen.add(id(const))
-            nbytes = getattr(const, "nbytes", 0)
-            if not nbytes or nbytes <= threshold:
-                continue
-            dt = np.dtype(getattr(const, "dtype", "uint8")).name
+            # jaxpr consts are typed literals that carry shape and dtype but
+            # no nbytes
+            dt = np.dtype(getattr(const, "dtype", "uint8"))
             shape = list(getattr(const, "shape", ()))
+            nbytes = int(np.prod(shape, dtype=np.int64)) * dt.itemsize
+            dt = dt.name
+            if nbytes <= threshold:
+                continue
             out.append(Issue(
                 "JX105",
                 f"`{name}` bakes a {nbytes}-byte constant ({dt}{shape}) "
@@ -390,7 +321,6 @@ def check_jx106_adjoint_contract(name, op, batch=4):
 
 IR_RULES = {
     "JX101": check_jx101_narrowing,
-    "JX103": check_jx103_dead_carry,
     "JX104": check_jx104_hot_transfer,
     "JX105": check_jx105_baked_const,
 }

@@ -27,8 +27,6 @@ _HINTS = {
     "JX102": "return strongly-typed arrays (jnp.asarray(..., dtype=...)); "
              "hoist shape-dependent Python branches into static dispatch "
              "documented as separate compile units",
-    "JX103": "drop the component from the carry (rebuild it after the loop "
-             "if the schema needs it) — see _qniht_core's exit_tol==0 carry",
     "JX104": "move the callback/transfer outside the loop and batch it, or "
              "pragma with why a per-iteration host hop is unavoidable",
     "JX105": "thread the array through the entry's signature so it ships as "

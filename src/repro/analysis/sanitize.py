@@ -105,15 +105,13 @@ def sanitize(nans: bool = True, infs: bool = True,
     Yields the :class:`CompileCounter` (the one passed in, or a fresh one).
     Previous debug-flag values are restored on exit, so nesting and test
     isolation are safe.
+
+    The flags are set thread-locally, not globally: a thread-local setting
+    installs the check on jit's C++ dispatch path itself. A global update
+    does not reach a thread where JAX has already run a local
+    ``debug_nans(False)`` scope (``core.jaxpr_as_fun`` does), so there a
+    cached jitted op (``jnp.true_divide``) would return an Inf unchecked.
     """
-    prev_nans = jax.config.jax_debug_nans
-    prev_infs = jax.config.jax_debug_infs
-    jax.config.update("jax_debug_nans", bool(nans))
-    jax.config.update("jax_debug_infs", bool(infs))
     own = counter if counter is not None else CompileCounter()
-    try:
-        with own:
-            yield own
-    finally:
-        jax.config.update("jax_debug_nans", prev_nans)
-        jax.config.update("jax_debug_infs", prev_infs)
+    with jax.debug_nans(bool(nans)), jax.debug_infs(bool(infs)), own:
+        yield own

@@ -298,9 +298,8 @@ def _solver_setup(
         else:
             # skip the residual matvecs (one of them streams dense f32 Φ —
             # benchmarks disable the trace so the loop is pure algorithm traffic)
-            # np-built so the intentional NaN marker is a transfer,
+            # np-built so the intentional NaN marker is a constant,
             # not an op that trips jax_debug_nans (see analysis.sanitize)
-            # jaxlint: allow=JX104 -- trace-time np constant: XLA folds the device_put and hoists it out of the loop
             rq = rt = jnp.asarray(np.full(X.shape[0], np.nan, np.float32))
         return X_new, (rq, rt, mu, changed, n_bt)
 
@@ -378,8 +377,7 @@ def _qniht_core(
                 # a done row recomputes itself identically (fixed point) —
                 # no masking needed, and the no-early-exit output is
                 # reproduced bit-for-bit. The lossless carry has no streak
-                # component: streak feeds only the stall heuristic below, and
-                # carrying it here hauls dead bytes every iteration (JX103).
+                # component: streak feeds only the stall heuristic below.
                 k, X, done, prev, bufs = st
                 X_new, outs = iteration(X, k)
                 bufs = jax.tree_util.tree_map(

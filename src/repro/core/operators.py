@@ -99,6 +99,8 @@ class DenseOperator:
     def nbytes(self) -> int:
         return self.mat.size * self.mat.dtype.itemsize
 
+    # Full f32 products on every backend: the TPU's default matmul precision
+    # rounds f32 operands to bf16, which would make the f32 baseline a bf16 one.
     def mv(self, x: jax.Array) -> jax.Array:
         # Contract x's minor axis against mat's minor axis directly: `x @ mat.T`
         # makes XLA:CPU materialize the transpose as a physical copy of Φ every
@@ -108,11 +110,13 @@ class DenseOperator:
         return jax.lax.dot_general(
             x.astype(dt), m.astype(dt),
             (((x.ndim - 1,), (1,)), ((), ())),
+            precision=jax.lax.Precision.HIGHEST,
         )
 
     def rmv(self, r: jax.Array) -> jax.Array:
         m = self.mat
-        return r @ (jnp.conj(m) if jnp.iscomplexobj(m) else m)
+        return jnp.matmul(r, jnp.conj(m) if jnp.iscomplexobj(m) else m,
+                          precision=jax.lax.Precision.HIGHEST)
 
     def tree_flatten(self):
         return (self.mat,), None

@@ -9,9 +9,11 @@ Each kernel ships as <name>/kernel.py (pl.pallas_call + BlockSpec VMEM tiling),
 * ``hsthresh``  — streaming hard-threshold H_s (paper §8's FPGA top-S search)
 * ``flashattn`` — fused online-softmax attention (32k-prefill substrate)
 
-CPU container note: kernels target TPU; ``interpret=True`` executes kernel
-bodies on CPU for correctness tests. ops.py wrappers auto-dispatch to ref.py
-off-TPU so the multi-pod dry-run lowers portable HLO.
+Backends: on a TPU the ops.py wrappers run the Pallas kernels (compiled by
+Mosaic; ``tests/test_tpu_compile.py`` compiles them for a described v5e at
+deployment widths). Elsewhere they dispatch to jnp paths (``qmm``'s fused
+pipeline, the ref.py oracles), and ``interpret=True`` executes the kernel
+bodies on CPU for correctness tests.
 """
 from repro.kernels.flashattn.ops import flash_attention
 from repro.kernels.hsthresh.ops import hsthresh

@@ -8,17 +8,29 @@ f32-accumulated matmul on 128-aligned tiles. Performance is therefore bound by
 ``T = size(Φ)/P`` on FPGA (supplementary §8.1).
 
 Layout contract (see ref.py): ``y[m, n] = Σ_k x[m, k] · ŵ[n, k]`` with ``ŵ``
-packed along K (minor-most axis → contiguous packed words).
+packed along K (minor-most axis → contiguous packed words), byte ``j`` of a row
+holding codes ``j·vpb + i`` in its bit field ``i`` (``vpb`` = values per byte).
+
+Planar pairing: a K-tile of ``bk`` codes arrives as ``bk/vpb`` packed bytes per
+row. Shifting out bit field ``i`` gives a *part* — a lane-dense ``(bn, bk/vpb)``
+block of the codes ``j·vpb + i``. Interleaving the parts back into storage order
+is a lane relayout the TPU compiler cannot fit in VMEM at 2 and 4 bits, so the
+kernel never does it: the wrappers permute ``x``'s columns once per call
+(:func:`planar_x`) so that part ``i`` pairs with the contiguous lane slice
+``[i·bk/vpb, (i+1)·bk/vpb)`` of the x tile, and the tile's dot is the sum of
+``vpb`` part dots. The packed format itself is unchanged (one format for the CPU
+fused path, the oracle and this kernel).
 
 Grid: ``(M/bm, N/bn, K/bk)``; K is the fastest-varying (sequential on TPU), and
 the output block (bm, bn) is revisited across the K steps and accumulated in
 place (initialized at k==0). Block shapes are chosen by
 :func:`select_block_config`: MXU-aligned ``bm=128, bn=128, bk=512`` for large
 problems, clamped down to the aligned problem size for small ones so tiny
-shapes (the recovery benchmarks run m=64, n=128) are not dwarfed by padding.
-Explicitly passed block shapes are validated strictly — misalignment or a
-block that pads the problem more than the hardware minima require raises
-instead of silently burning bandwidth on padding.
+shapes (the recovery benchmarks run m=64, n=128) are not dwarfed by padding,
+and shrunk until the step's VMEM estimate (:func:`vmem_bytes`) fits the budget.
+Explicitly passed block shapes are validated strictly — misalignment, a block
+that pads the problem more than the hardware minima require, or one over the
+VMEM budget raises instead of failing in the TPU compiler.
 
 Two scale layouts, two kernels:
 
@@ -26,10 +38,12 @@ Two scale layouts, two kernels:
   dot runs on unit-scale codes and the scale multiplies the *accumulated*
   (bm, bn) block (cheapest; per_tensor is the broadcast special case).
 * :func:`qmm_group_pallas` — blockwise scales along the contraction axis,
-  shape (N, K/g): each K-tile loads its (bn, bk/g) scale slab alongside the
-  packed codes and applies it to the codes *before* the dot (the scale varies
-  within the contraction, so it cannot be factored out of the accumulator).
-  ``g`` must divide ``block_k`` so scale slabs tile cleanly.
+  shape (N, K/g), streamed transposed as (K/g, N) so each K-tile's scale slab
+  ``(bk/g, bn)`` is a legal (8, 128)-tiled block. The scale varies within the
+  contraction, so it cannot multiply the accumulator directly; instead the x
+  tile is expanded to one row block per scale group (x masked to that group's
+  columns), one dot yields every group's partial sums at once, and each
+  partial is scaled on the output side. ``bk/g`` must be a multiple of 8.
 """
 from __future__ import annotations
 
@@ -52,9 +66,29 @@ _DEFAULT_BM = 128
 _DEFAULT_BN = 128
 _DEFAULT_BK = 512
 
+# The TPU compiler grants a kernel 16 MiB of scoped VMEM by default (v5e);
+# tiles are sized against half of it so the compiler's own scratch fits too.
+VMEM_BUDGET_BYTES = 8 * 1024 * 1024
+
 
 def _round_up(value: int, multiple: int) -> int:
     return -(-value // multiple) * multiple
+
+
+def vmem_bytes(bm: int, bn: int, bk: int, bits: int,
+               group_size: int | None = None) -> int:
+    """Estimated VMEM bytes of one grid step: double-buffered input and output
+    blocks plus the in-kernel temporaries (unpacked parts, and for the group
+    kernel the group-expanded x tile and its partial sums)."""
+    vpb = BY_BITS[bits].values_per_byte
+    bkp = bk // vpb
+    scale_blk = 4 * (max(8, bk // group_size) if group_size else 8) * bn
+    blocks = 2 * (4 * bm * bk + bn * bkp + scale_blk) + 2 * 4 * bm * bn
+    temps = 4 * bn * bkp + 2 * 4 * bn * bk      # int32 words + f32 parts
+    if group_size:
+        rows = (bk // group_size) * bm
+        temps += 4 * rows * bk + 2 * 4 * rows * bn
+    return blocks + temps
 
 
 def select_block_config(
@@ -73,15 +107,20 @@ def select_block_config(
     Auto mode (a block dim left ``None``): start from the MXU defaults and
     clamp each tile to the problem dimension rounded up to its hardware
     minimum, so small problems (the fig5 bench runs m=64, n=128) pay only the
-    unavoidable alignment padding instead of a full 128×128×512 tile.
+    unavoidable alignment padding instead of a full 128×128×512 tile; then
+    shrink the free dims (bk first, then bm, then bn) until
+    :func:`vmem_bytes` fits :data:`VMEM_BUDGET_BYTES`.
 
     Explicit mode (a block dim passed): validate strictly — misaligned blocks,
-    ``g ∤ bk``, or a block that exceeds the aligned problem size (pure padding)
-    raise ``ValueError`` instead of silently blowing up the padded footprint.
+    a K step that is not a whole number of 8-row scale slabs (group kernel),
+    a block that exceeds the aligned problem size (pure padding), or a tile
+    set over the VMEM budget raise ``ValueError`` instead of silently blowing
+    up the padded footprint or failing in the TPU compiler.
     """
     k_unit = 128 * BY_BITS[bits].values_per_byte
     if group_size is not None:
-        k_unit = math.lcm(k_unit, group_size)
+        # the transposed scale slab (bk/g, bn) must tile 8 sublanes
+        k_unit = math.lcm(k_unit, 8 * group_size)
 
     m_cap = _round_up(max(m, 1), _MIN_BM)
     n_cap = _round_up(max(n, 1), _MIN_BN)
@@ -117,7 +156,7 @@ def select_block_config(
             raise ValueError(
                 f"block_k={bk} must be a multiple of {k_unit} "
                 f"(128 lanes × values/byte at {bits} bits"
-                + (f", lcm group_size={group_size}" if group_size else "")
+                + (f", lcm 8 × group_size={group_size}" if group_size else "")
                 + ")"
             )
         if bk > k_cap:
@@ -125,59 +164,96 @@ def select_block_config(
                 f"block_k={bk} exceeds aligned problem size {k_cap} (k={k_dim}): "
                 "the tile would be mostly padding; shrink it or leave it unset"
             )
+
+    while vmem_bytes(bm, bn, bk, bits, group_size) > VMEM_BUDGET_BYTES:
+        if block_k is None and bk > k_unit:
+            bk = _round_up(bk // 2, k_unit)
+        elif block_m is None and bm > _MIN_BM:
+            bm = _round_up(bm // 2, _MIN_BM)
+        elif block_n is None and bn > _MIN_BN:
+            bn = _round_up(bn // 2, _MIN_BN)
+        else:
+            raise ValueError(
+                f"blocks ({bm}, {bn}, {bk}) at {bits} bits need "
+                f"~{vmem_bytes(bm, bn, bk, bits, group_size)} B of VMEM, over "
+                f"the {VMEM_BUDGET_BYTES} B budget; shrink them or leave them "
+                "unset")
     return bm, bn, bk
 
 
-def _unpack_block(w_packed_blk: jnp.ndarray, bits: int) -> jnp.ndarray:
-    """(bn, bk/vpb) uint8 → (bn, bk) f32 codes (unit scale, in [-K, K])."""
+def planar_x(x: jax.Array, bits: int, block_k: int) -> jax.Array:
+    """Permute the columns of x (M, K) within every K-tile of ``block_k`` so
+    that column ``j·vpb + i`` of the tile moves to ``i·(block_k/vpb) + j``:
+    part ``i`` of the tile's packed codes then pairs with a contiguous lane
+    slice of x (see the module docstring). Identity at 8 bits."""
+    vpb = BY_BITS[bits].values_per_byte
+    if vpb == 1:
+        return x
+    m, k = x.shape
+    return (x.reshape(m, k // block_k, block_k // vpb, vpb)
+            .swapaxes(2, 3).reshape(m, k))
+
+
+def _unpack_parts(w_packed_blk: jnp.ndarray, bits: int) -> list[jnp.ndarray]:
+    """(bn, bkp) uint8 → vpb lane-dense (bn, bkp) f32 unit-scale code blocks;
+    part ``i`` holds the codes ``j·vpb + i`` of the tile."""
     fmt = BY_BITS[bits]
-    vpb = fmt.values_per_byte
     k = fmt.half_steps
     w32 = w_packed_blk.astype(jnp.int32)
-    if vpb == 1:
-        codes = w32
-    else:
-        mask = (1 << bits) - 1
-        parts = [(w32 >> (bits * i)) & mask for i in range(vpb)]
-        # parts[i] holds code (j*vpb + i): interleave on a new minor axis.
-        codes = jnp.stack(parts, axis=-1).reshape(w32.shape[0], w32.shape[1] * vpb)
-    return (codes - k).astype(jnp.float32)
+    if fmt.values_per_byte == 1:
+        return [(w32 - k).astype(jnp.float32)]
+    mask = (1 << bits) - 1
+    return [(((w32 >> (bits * i)) & mask) - k).astype(jnp.float32)
+            for i in range(fmt.values_per_byte)]
 
 
-def _qmm_kernel(x_ref, w_ref, scale_ref, o_ref, *, bits: int, n_k_steps: int):
-    k_step = pl.program_id(2)
+def _dot_nt(a, b):
+    """(r, c) × (n, c) → (r, n), contracting the lane axis of both, in full
+    f32 (the MXU default would round the f32 x operand to bf16)."""
+    return jax.lax.dot_general(
+        a, b, (((1,), (1,)), ((), ())), precision=jax.lax.Precision.HIGHEST,
+        preferred_element_type=jnp.float32)
 
-    @pl.when(k_step == 0)
+
+def _qmm_kernel(x_ref, w_ref, scale_ref, o_ref, *, bits: int):
+    @pl.when(pl.program_id(2) == 0)
     def _init():
         o_ref[...] = jnp.zeros_like(o_ref)
 
-    x_blk = x_ref[...].astype(jnp.float32)              # (bm, bk)
-    codes = _unpack_block(w_ref[...], bits)             # (bn, bk) unit-scale codes
-    # contract over k: (bm, bk) x (bn, bk) -> (bm, bn)
-    acc = jax.lax.dot_general(
-        x_blk, codes, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-    )
+    parts = _unpack_parts(w_ref[...], bits)             # vpb × (bn, bkp)
+    bkp = parts[0].shape[1]
+    acc = None
+    for i, part in enumerate(parts):
+        x_i = x_ref[:, i * bkp:(i + 1) * bkp].astype(jnp.float32)   # (bm, bkp)
+        d = _dot_nt(x_i, part)
+        acc = d if acc is None else acc + d
     o_ref[...] += acc * (scale_ref[...] / BY_BITS[bits].half_steps)  # (1, bn) bcast
 
 
 def _qmm_group_kernel(x_ref, w_ref, scale_ref, o_ref, *, bits: int, group_size: int):
-    k_step = pl.program_id(2)
-
-    @pl.when(k_step == 0)
+    @pl.when(pl.program_id(2) == 0)
     def _init():
         o_ref[...] = jnp.zeros_like(o_ref)
 
-    x_blk = x_ref[...].astype(jnp.float32)              # (bm, bk)
-    codes = _unpack_block(w_ref[...], bits)             # (bn, bk) unit-scale codes
-    scales = scale_ref[...]                             # (bn, bk/g)
-    bn, bkg = scales.shape
-    # dequantize in-register: code (n, j) scales with scales[n, j // g]
-    w_blk = (codes.reshape(bn, bkg, group_size) * scales[:, :, None]
-             ).reshape(bn, bkg * group_size) * (1.0 / BY_BITS[bits].half_steps)
-    acc = jax.lax.dot_general(
-        x_blk, w_blk, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-    )
-    o_ref[...] += acc
+    parts = _unpack_parts(w_ref[...], bits)             # vpb × (bn, bkp)
+    bkp = parts[0].shape[1]
+    scales = scale_ref[...]                             # (bkg, bn), transposed
+    bkg, bn = scales.shape
+    bm = x_ref.shape[0]
+    # within every part, column j belongs to scale group j // (g / vpb)
+    gp = group_size // BY_BITS[bits].values_per_byte
+    grp = jax.lax.broadcasted_iota(jnp.int32, (bkg, 1, bkp), 0) * gp
+    col = jax.lax.broadcasted_iota(jnp.int32, (bkg, 1, bkp), 2)
+    in_group = (col >= grp) & (col < grp + gp)
+    acc = None
+    for i, part in enumerate(parts):
+        x_i = x_ref[:, i * bkp:(i + 1) * bkp].astype(jnp.float32)   # (bm, bkp)
+        # row block q of x_g is x restricted to scale group q's columns
+        x_g = jnp.where(in_group, x_i[None], 0.0).reshape(bkg * bm, bkp)
+        d = _dot_nt(x_g, part)                          # (bkg·bm, bn) partials
+        acc = d if acc is None else acc + d
+    scaled = acc.reshape(bkg, bm, bn) * scales[:, None, :]
+    o_ref[...] += jnp.sum(scaled, axis=0) * (1.0 / BY_BITS[bits].half_steps)
 
 
 @functools.partial(
@@ -209,8 +285,9 @@ def qmm_group_pallas(
     if k % block_k or m % block_m or n % block_n:
         raise ValueError(f"shapes ({m},{k}),({n}) must be multiples of blocks "
                          f"({block_m},{block_n},{block_k}); pad in ops.py")
-    if block_k % group_size:
-        raise ValueError(f"group_size {group_size} must divide block_k {block_k}")
+    if block_k % (8 * group_size):
+        raise ValueError(f"block_k {block_k} must be a multiple of 8 × group_size "
+                         f"{group_size} (the scale slab tiles 8 sublanes)")
     if w_packed.shape[1] * vpb != k:
         raise ValueError("w_packed minor dim inconsistent with k_dim/bits")
     if scale.shape != (n, k // group_size):
@@ -224,12 +301,12 @@ def qmm_group_pallas(
         in_specs=[
             pl.BlockSpec((block_m, block_k), lambda i, j, kk: (i, kk)),
             pl.BlockSpec((block_n, bk_packed), lambda i, j, kk: (j, kk)),
-            pl.BlockSpec((block_n, bk_groups), lambda i, j, kk: (j, kk)),
+            pl.BlockSpec((bk_groups, block_n), lambda i, j, kk: (kk, j)),
         ],
         out_specs=pl.BlockSpec((block_m, block_n), lambda i, j, kk: (i, j)),
         out_shape=jax.ShapeDtypeStruct((m, n), jnp.float32),
         interpret=interpret,
-    )(x, w_packed, scale)
+    )(planar_x(x, bits, block_k), w_packed, scale.T)
 
 
 @functools.partial(
@@ -263,7 +340,7 @@ def qmm_pallas(
     bk_packed = block_k // vpb
     grid = (m // block_m, n // block_n, k // block_k)
     return pl.pallas_call(
-        functools.partial(_qmm_kernel, bits=bits, n_k_steps=grid[2]),
+        functools.partial(_qmm_kernel, bits=bits),
         grid=grid,
         in_specs=[
             pl.BlockSpec((block_m, block_k), lambda i, j, kk: (i, kk)),
@@ -273,4 +350,4 @@ def qmm_pallas(
         out_specs=pl.BlockSpec((block_m, block_n), lambda i, j, kk: (i, j)),
         out_shape=jax.ShapeDtypeStruct((m, n), jnp.float32),
         interpret=interpret,
-    )(x, w_packed, scale)
+    )(planar_x(x, bits, block_k), w_packed, scale)

@@ -15,6 +15,10 @@ from repro.kernels.sqround.kernel import sqround_pallas
 from repro.kernels.sqround.ref import sqround_ref
 
 
+# Column tile: a 65,536-column operator row never has to fit VMEM whole.
+_BLOCK_C = 512
+
+
 def _round_up(v: int, mult: int) -> int:
     return (v + mult - 1) // mult * mult
 
@@ -29,7 +33,11 @@ def sqround(
     interpret: bool = False,
     block_r: int = 256,
 ) -> tuple[jax.Array, jax.Array]:
-    """Stochastically round a 2-D float32 array to int8 codes in [-K, K]."""
+    """Stochastically round a 2-D float32 array to int8 codes in [-K, K].
+
+    Tiles are ``min(block_r, R) × min(512, C)``; a dimension larger than its
+    tile is padded up to a tile multiple (``block_r`` a multiple of 32, the
+    int8 output's sublane tile)."""
     if v.ndim != 2:
         raise ValueError("sqround expects a 2-D array")
     if use_pallas is None:
@@ -41,11 +49,11 @@ def sqround(
     if not use_pallas:
         return sqround_ref(v, u, scale, bits), scale
     r, c = v.shape
-    br = min(block_r, r)
-    rp = _round_up(r, br)
-    v_p = jnp.pad(v, ((0, rp - r), (0, 0)))
-    u_p = jnp.pad(u, ((0, rp - r), (0, 0)))
+    br, bc = min(block_r, r), min(_BLOCK_C, c)
+    rp, cp = _round_up(r, br), _round_up(c, bc)
+    pad = ((0, rp - r), (0, cp - c))
     codes = sqround_pallas(
-        v_p, u_p, scale.reshape(1, 1), bits=bits, block_r=br, interpret=interpret
+        jnp.pad(v, pad), jnp.pad(u, pad), scale.reshape(1, 1), bits=bits,
+        block_r=br, block_c=bc, interpret=interpret,
     )
-    return codes[:r], scale
+    return codes[:r, :c], scale

@@ -20,7 +20,7 @@ Backends (``--backend``):
 ``--batch B`` recovers B observations of the same Φ̂ at once (``qniht_batch``):
 one packed Φ̂ stream serves the whole batch per iteration. Adding
 ``--devices N`` splits those rows over an N-device ``("batch",)`` mesh
-(``qniht_batch_sharded`` — bit-identical per item, with per-shard early exit;
+(``qniht_batch_sharded`` — the same computation per item, with per-shard early exit;
 on CPU the driver forces N host devices for you). The multi-chunk streaming
 loop lives in ``python -m repro.launch.serve``.
 
@@ -78,6 +78,7 @@ from repro.core import (
     source_recovery,
     support_recovery,
 )
+from repro.launch.compile_cache import enable_compile_cache
 from repro.sensing import (
     Station,
     brain_phantom,
@@ -92,9 +93,9 @@ from repro.sensing import (
 )
 
 
-def _batch_solver(devices, kw, ckpt=None):
+def batch_solver(devices, kw, ckpt=None):
     """qniht_batch, or its mesh-sharded twin when ``--devices`` asks for one
-    (bit-identical per item — see repro.parallel.batch). ``early_exit`` is on
+    (the same computation per item — see repro.parallel.batch). ``early_exit`` is on
     whenever the per-iteration operators are stationary (it is invalid under
     requantize='pair', which redraws Φ̂ each iteration).
 
@@ -117,6 +118,44 @@ def _batch_solver(devices, kw, ckpt=None):
     return qniht_batch
 
 
+def solve_batch(solver, phi, Y, s, n_iters, **kw):
+    """Run a batched solve with its compilation timed apart from the solve.
+
+    Returns ``(result, compile_s, solve_s, compiled)``. A jitted solver
+    (``qniht_batch``) is compiled ahead of time, so ``compile_s`` is the
+    compile alone — a persistent-cache hit shows as a short one — and
+    ``compiled`` is the executable (its ``as_text()`` names the kernels it
+    calls). Other solvers (sharded, checkpointed) compile inside their first
+    call: ``compile_s`` and ``compiled`` are then ``None`` and ``solve_s``
+    includes the compile."""
+    if not hasattr(solver, "lower"):
+        t0 = time.perf_counter()
+        res = solver(phi, Y, s, n_iters, **kw)
+        jax.block_until_ready(res.x)
+        return res, None, time.perf_counter() - t0, None
+    key = kw.pop("key", None)
+    t0 = time.perf_counter()
+    compiled = solver.lower(phi, Y, s, n_iters, key=key, **kw).compile()
+    t1 = time.perf_counter()
+    res = compiled(phi, Y, key=key)
+    jax.block_until_ready(res.x)
+    return res, t1 - t0, time.perf_counter() - t1, compiled
+
+
+def _batch_metrics(X_true, res, compile_s, solve_s, compiled):
+    """Per-batch summary shared by the batched recover paths."""
+    batch = X_true.shape[0]
+    rel = [float(relative_error(res.x[b], X_true[b])) for b in range(batch)]
+    out = {"batch": batch, "rel_error_mean": sum(rel) / batch,
+           "rel_error_max": max(rel),
+           "finite": bool(jnp.all(jnp.isfinite(res.x)))}
+    if compiled is not None:
+        out["compile_s"] = compile_s
+        out["pallas_kernel"] = "tpu_custom_call" in compiled.as_text()
+    out["wall_s"] = solve_s
+    return out
+
+
 def _single_via_ckpt(ckpt, phi, y, s, n_iters, **kw):
     """One-problem solve through the segmented checkpointed driver (wraps the
     observation as a 1-row batch, exactly what ``qniht`` itself does)."""
@@ -128,8 +167,8 @@ def _single_via_ckpt(ckpt, phi, y, s, n_iters, **kw):
                      trace=jax.tree_util.tree_map(lambda t: t[:, 0], res.trace))
 
 
-def _solver_kwargs(backend, bits_phi, bits_y, key, requantize,
-                   granularity="per_tensor", group_size=None):
+def solver_kwargs(backend, bits_phi, bits_y, key, requantize,
+                  granularity="per_tensor", group_size=None):
     if granularity != "per_tensor" and backend != "packed":
         raise ValueError(
             f"--scale-granularity {granularity} scales the packed Φ̂ stream; "
@@ -148,27 +187,35 @@ def _solver_kwargs(backend, bits_phi, bits_y, key, requantize,
     return kw
 
 
+def lofar_problem(cs, key, batch):
+    """The station's Φ and ``batch`` random skies with their visibilities:
+    ``(phi, Y, X_true)``, deterministic in ``key`` (row b folds in b)."""
+    st = Station(n_antennas=cs.n_antennas, seed=cs.seed)
+    phi = measurement_matrix(st, cs.resolution, cs.extent)
+    skies = [make_sky(cs.resolution, cs.n_sources, jax.random.fold_in(key, b),
+                      min_sep=cs.min_sep) for b in range(batch)]
+    Y = jnp.stack([visibilities(phi, x, cs.snr_db, jax.random.fold_in(key, b))[0]
+                   for b, x in enumerate(skies)])
+    return phi, Y, jnp.stack(skies)
+
+
 def recover_lofar(cs, backend, bits_phi, bits_y, key, requantize="pair", batch=0,
                   granularity="per_tensor", group_size=None, devices=None,
                   ckpt=None):
+    kw = solver_kwargs(backend, bits_phi, bits_y, key, requantize,
+                       granularity, group_size)
+    if batch:
+        phi, Y, X_true = lofar_problem(cs, key, batch)
+        res, compile_s, solve_s, compiled = solve_batch(
+            batch_solver(devices, kw, ckpt), phi, Y, cs.n_sources, cs.n_iters,
+            real_signal=True, nonneg=True, **kw)
+        sup = [float(support_recovery(res.x[b], X_true[b], cs.n_sources))
+               for b in range(batch)]
+        return {**_batch_metrics(X_true, res, compile_s, solve_s, compiled),
+                "support_recovery_mean": sum(sup) / batch,
+                "support_recovery_min": min(sup)}
     st = Station(n_antennas=cs.n_antennas, seed=cs.seed)
     phi = measurement_matrix(st, cs.resolution, cs.extent)
-    kw = _solver_kwargs(backend, bits_phi, bits_y, key, requantize,
-                        granularity, group_size)
-    if batch:
-        skies = [make_sky(cs.resolution, cs.n_sources, jax.random.fold_in(key, b),
-                          min_sep=cs.min_sep) for b in range(batch)]
-        Y = jnp.stack([visibilities(phi, x, cs.snr_db, jax.random.fold_in(key, b))[0]
-                       for b, x in enumerate(skies)])
-        X_true = jnp.stack(skies)
-        t0 = time.time()
-        res = _batch_solver(devices, kw, ckpt)(phi, Y, cs.n_sources, cs.n_iters,
-                                               real_signal=True, nonneg=True, **kw)
-        jax.block_until_ready(res.x)
-        wall = time.time() - t0
-        rel = [float(relative_error(res.x[b], X_true[b])) for b in range(batch)]
-        return {"batch": batch, "rel_error_mean": sum(rel) / batch,
-                "rel_error_max": max(rel), "wall_s": wall}
     x = make_sky(cs.resolution, cs.n_sources, key, min_sep=cs.min_sep)
     y, _ = visibilities(phi, x, cs.snr_db, key)
     t0 = time.time()
@@ -197,8 +244,8 @@ def recover_gaussian(g, backend, bits_phi, bits_y, key, requantize="pair", batch
                      granularity="per_tensor", group_size=None, devices=None,
                      ckpt=None):
     prob = make_gaussian_problem(g.m, g.n, g.s, 20.0, key)
-    kw = _solver_kwargs(backend, bits_phi, bits_y, key, requantize,
-                        granularity, group_size)
+    kw = solver_kwargs(backend, bits_phi, bits_y, key, requantize,
+                       granularity, group_size)
     if batch:
         # B problems sharing phi: fresh sparse signals + noise per row.
         probs = [make_gaussian_problem(g.m, g.n, g.s, 20.0,
@@ -206,12 +253,8 @@ def recover_gaussian(g, backend, bits_phi, bits_y, key, requantize="pair", batch
                                        phi=prob.phi) for b in range(batch)]
         Y = jnp.stack([p.y for p in probs])
         X_true = jnp.stack([p.x_true for p in probs])
-        t0 = time.time()
-        res = _batch_solver(devices, kw, ckpt)(prob.phi, Y, g.s, g.n_iters, **kw)
-        jax.block_until_ready(res.x)
-        rel = [float(relative_error(res.x[b], X_true[b])) for b in range(batch)]
-        return {"batch": batch, "rel_error_mean": sum(rel) / batch,
-                "rel_error_max": max(rel), "wall_s": time.time() - t0}
+        return _batch_metrics(X_true, *solve_batch(
+            batch_solver(devices, kw, ckpt), prob.phi, Y, g.s, g.n_iters, **kw))
     if ckpt:
         res = _single_via_ckpt(ckpt, prob.phi, prob.y, g.s, g.n_iters, **kw)
     else:
@@ -271,10 +314,8 @@ def recover_mri(cfg, bits_y, key, batch=0, granularity="per_tensor", n_bands=Non
         Y, _ = mri_observations(getattr(prob.op, "kspace_op", prob.op), Img_true,
                                 cfg.snr_db, jax.random.fold_in(key, batch))
         Y = prep(Y)
-        t0 = time.time()
-        res = _batch_solver(devices, kw, ckpt)(prob.op, Y, cfg.n_sparse, cfg.n_iters, **kw)
-        jax.block_until_ready(res.x)
-        wall = time.time() - t0
+        res, _, wall, _ = solve_batch(batch_solver(devices, kw, ckpt), prob.op, Y,
+                                      cfg.n_sparse, cfg.n_iters, **kw)
         Img_hat = prob.to_image(res.x)
         ps = [float(psnr(Img_hat[b].reshape(r, r), Img_true[b].reshape(r, r)))
               for b in range(batch)]
@@ -327,7 +368,7 @@ def main(argv=None):
                     help="recover B observations of one Φ̂ at once (qniht_batch)")
     ap.add_argument("--devices", type=int, default=None,
                     help="shard the --batch rows over an N-device ('batch',) "
-                         "mesh (qniht_batch_sharded; bit-identical per item). "
+                         "mesh (qniht_batch_sharded; the same computation per item). "
                          "On CPU this also forces N host devices when jax has "
                          "not initialized yet")
     ap.add_argument("--scale-granularity", default=None,
@@ -368,6 +409,7 @@ def main(argv=None):
                          "under this directory (TensorBoard/Perfetto format; "
                          "see docs/performance.md)")
     args = ap.parse_args(argv)
+    enable_compile_cache()
     if (args.resume or args.ckpt_every != 10) and not args.checkpoint_dir:
         ap.error("--resume/--ckpt-every need --checkpoint-dir")
     if args.ckpt_every < 1:

@@ -2,7 +2,7 @@
 
 * **solver serving** (:mod:`repro.parallel.batch`,
   :mod:`repro.parallel.scheduler`): the B axis of ``qniht_batch`` sharded
-  over a 1-D ``batch`` mesh, bit-identical per item; the continuous-batching
+  over a 1-D ``batch`` mesh, the same computation per item; the continuous-batching
   scheduler refills freed rows of the live state from an admission queue.
 * **model training** (:mod:`repro.parallel.sharding`,
   :mod:`repro.parallel.collectives`): parameter sharding rules and quantized

@@ -15,14 +15,17 @@ parallel, and this module maps it onto a mesh:
   per-item convergence flags that drive ``early_exit``.
 * **replicated** — the operator (dense Φ, packed codes + scales, or a
   matrix-free operator's parameters) and the PRNG key. Each shard re-derives
-  exactly the quantization draws the single-device path uses, which is what
-  makes the result bit-identical per item rather than merely statistically
-  equivalent.
+  exactly the quantization draws the single-device path uses, so per item the
+  result is the same computation rather than merely statistically
+  equivalent. It is bit-identical only where the compiler sums each row's
+  products in the same order at both batch shapes: the tests' sizes on CPU
+  are; the LOFAR CS302 int2 batch (16 rows on one TPU v5e chip vs 4 per chip
+  on four) differs by up to 1.2e-7 per item.
 
-Implementation: :func:`jax.experimental.shard_map` around the shared batched
-core ``repro.core.niht._qniht_core`` (``check_rep=False`` — the loop's
-``lax.while_loop`` backtracking has no replication rule, and the program
-contains no collectives to mis-infer: shards are fully independent). B is
+Implementation: :func:`jax.shard_map` around the shared batched core
+``repro.core.niht._qniht_core`` (``check_vma=False`` — the program contains
+no collectives, shards are fully independent, so there is nothing for the
+varying-manual-axes check to verify). B is
 zero-padded up to a multiple of the mesh size; an all-zero row is accepted at
 iteration 0 and immediately flagged converged, so padding never slows a shard
 down. ``jax.jit`` over static solver config gives the compile cache the
@@ -47,11 +50,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-
-try:  # JAX ≤ 0.4.x ships shard_map under experimental
-    from jax.experimental.shard_map import shard_map as _shard_map
-except ImportError:  # newer JAX promoted it to the top level
-    _shard_map = jax.shard_map
 
 from repro.core.niht import (
     _SEG_DEFAULTS,
@@ -144,11 +142,11 @@ def _sharded_call(phi, Y, key, *, mesh, **statics):
             statics["unroll"],
         )
 
-    fn = _shard_map(
+    fn = jax.shard_map(
         local, mesh=mesh,
         in_specs=(P(), P(BATCH_AXIS), P()),
         out_specs=_OUT_SPECS,
-        check_rep=False,  # lax.while_loop has no replication rule (JAX ≤ 0.4)
+        check_vma=False,
     )
     return fn(phi, Y, key)
 
@@ -311,12 +309,12 @@ def state_shardings(mesh: Mesh) -> SolverState:
 
 @partial(jax.jit, static_argnames=("mesh",) + _SEG_STATIC)
 def _sharded_segment_call(phi, state, *, mesh, n_steps, **statics):
-    fn = _shard_map(
+    fn = jax.shard_map(
         lambda phi_, st: _segment_core(phi_, st, n_steps=n_steps, **statics),
         mesh=mesh,
         in_specs=(P(), _SEG_SPECS),
         out_specs=_SEG_SPECS,
-        check_rep=False,  # lax.while_loop has no replication rule (JAX ≤ 0.4)
+        check_vma=False,
     )
     return fn(phi, state)
 

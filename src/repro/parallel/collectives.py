@@ -39,11 +39,6 @@ from jax.sharding import Mesh, PartitionSpec as P
 
 from repro.quant.formats import BY_BITS
 
-try:  # JAX ≤ 0.4.x ships shard_map under experimental
-    from jax.experimental.shard_map import shard_map as _shard_map
-except ImportError:  # newer JAX promoted it to the top level
-    _shard_map = jax.shard_map
-
 
 def _quantize_shard(g: jax.Array, scale: jax.Array, bits: int, key: jax.Array):
     k = BY_BITS[bits].half_steps
@@ -96,7 +91,7 @@ def make_qgrad_allreduce(mesh: Mesh, axis_name: str, bits: int):
     def run(tree, key):
         flat, treedef = jax.tree_util.tree_flatten(tree)
         specs = tuple(P(axis_name, *([None] * (g.ndim - 1))) for g in flat)
-        fn = _shard_map(
+        fn = jax.shard_map(
             per_shard,
             mesh=mesh,
             in_specs=(specs, P()),

@@ -86,7 +86,9 @@ def measurement_matrix(
     """Φ ∈ C^{L² × r²}: Φ_{z,w} = exp(−j2π ⟨u_z, r_w⟩)   (Eq. 75)."""
     uv = jnp.asarray(station.baselines(), dtype=jnp.float32)         # (M, 2)
     grid = jnp.asarray(sky_grid(resolution, extent), dtype=jnp.float32)  # (N, 2)
-    phase = -2.0 * jnp.pi * (uv @ grid.T)                            # (M, N)
+    # two-term inner products written out: a matmul at the TPU's default
+    # precision would round the coordinates to bf16 (phases off by ~0.4 rad)
+    phase = -2.0 * jnp.pi * (uv[:, :1] * grid[:, 0] + uv[:, 1:] * grid[:, 1])
     return jnp.exp(1j * phase.astype(jnp.float32)).astype(dtype)
 
 
@@ -98,7 +100,7 @@ def visibilities(
 ) -> tuple[jax.Array, jax.Array]:
     """y = Φx + e with circularly-symmetric complex Gaussian noise at the given
     *antenna-level* SNR (paper §4 uses 0 dB). Returns (y, e)."""
-    clean_y = phi @ x.astype(phi.dtype)
+    clean_y = jnp.matmul(phi, x.astype(phi.dtype), precision=jax.lax.Precision.HIGHEST)
     if snr_db is None:
         return clean_y, jnp.zeros_like(clean_y)
     m = clean_y.shape[0]

@@ -53,23 +53,6 @@ def _jx102_shape_branch():
                      anchor=anchor_of(fn))
 
 
-def _jx103_dead_carry():
-    import jax
-    import jax.numpy as jnp
-
-    def fn(x):
-        def body(carry, _):
-            acc, dead = carry
-            return (acc + 1.0, dead), acc  # `dead` hauled, never read
-
-        (acc, _), ys = jax.lax.scan(body, (x, jnp.zeros((32,))), None,
-                                    length=4)
-        return acc, ys
-
-    return TraceSpec(fn=fn, args=(jax.ShapeDtypeStruct((8,), jnp.float32),),
-                     anchor=anchor_of(fn))
-
-
 def _jx104_callback_in_loop():
     import jax
 
@@ -154,7 +137,6 @@ ENTRIES = [
     EntryPoint("fixture.jx101.narrowing", _jx101_narrowing),
     EntryPoint("fixture.jx102.weak_output", _jx102_weak_output),
     EntryPoint("fixture.jx102.shape_branch", _jx102_shape_branch),
-    EntryPoint("fixture.jx103.dead_carry", _jx103_dead_carry),
     EntryPoint("fixture.jx104.callback_in_loop", _jx104_callback_in_loop),
     EntryPoint("fixture.jx105.baked_const", _jx105_baked_const),
     EntryPoint("fixture.jx106.broken_adjoint", _jx106_broken),

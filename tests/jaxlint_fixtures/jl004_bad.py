@@ -4,7 +4,7 @@ from functools import partial
 
 import jax
 from jax import lax
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 
 
 @partial(shard_map, mesh=None, in_specs=None, out_specs=None)

@@ -57,7 +57,7 @@ def test_unknown_rule_id_is_a_usage_error(tmp_repo):
 def test_rule_filter_crossing_tiers_is_a_usage_error(tmp_repo):
     # a JX-only filter with --tier ast selects nothing runnable
     with pytest.raises(SystemExit) as e:
-        _run(tmp_repo, "--tier", "ast", "--rules", "JX103")
+        _run(tmp_repo, "--tier", "ast", "--rules", "JX104")
     assert e.value.code == 2
 
 
@@ -111,7 +111,7 @@ def test_update_baseline_round_trip_then_stale_rejection(tmp_repo, capsys):
 
 def test_update_baseline_preserves_other_tiers_entries(tmp_repo):
     bl = tmp_repo / BASELINE_NAME
-    jx_entry = {"rule": "JX103", "path": "src/other.py",
+    jx_entry = {"rule": "JX104", "path": "src/other.py",
                 "snippet": "while_loop(...)",
                 "reason": "vetted: schema uniformity"}
     bl.write_text(json.dumps({"version": 1, "entries": [jx_entry]}, indent=2))
@@ -119,8 +119,8 @@ def test_update_baseline_preserves_other_tiers_entries(tmp_repo):
     assert _run(tmp_repo, "--update-baseline", "--tier", "ast") == 0
     entries = json.loads(bl.read_text())["entries"]
     rules = sorted(e["rule"] for e in entries)
-    assert rules == ["JL001", "JX103"]
-    assert [e for e in entries if e["rule"] == "JX103"][0] == jx_entry
+    assert rules == ["JL001", "JX104"]
+    assert [e for e in entries if e["rule"] == "JX104"][0] == jx_entry
 
 
 def test_stale_check_skipped_for_explicit_paths(tmp_repo):

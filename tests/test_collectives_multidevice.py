@@ -2,9 +2,8 @@
 the main pytest process keeps its single-device view (per the dry-run rules).
 
 ``test_qgrad_allreduce_host_mesh`` is the fast in-process regression for the
-shard_map entry point (JAX 0.4.x has it under ``jax.experimental.shard_map``,
-not ``jax.shard_map``) so an import/dispatch break surfaces in the quick tier,
-not only in the slow subprocess test."""
+``jax.shard_map`` entry point, so an import/dispatch break surfaces in the
+quick tier, not only in the slow subprocess test."""
 import os
 import subprocess
 import sys

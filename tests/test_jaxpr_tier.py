@@ -8,6 +8,7 @@ registry must keep tracing clean. Mirrors tests/test_jaxlint.py for the
 AST tier.
 """
 import os
+import re
 
 import pytest
 
@@ -20,6 +21,8 @@ from repro.analysis.jaxpr.runner import load_registry_file, run_jaxpr_tier
 REPO = find_repo_root(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 FIXTURE_REGISTRY = os.path.join(REPO, "tests", "jaxlint_fixtures",
                                 "jaxpr_bad.py")
+# an inline JX pragma comment (not a docstring that mentions the syntax)
+_JX_PRAGMA = re.compile(r"(?:^|\s)#\s*jaxlint:\s*allow=JX\d")
 
 
 @pytest.fixture(scope="module")
@@ -61,7 +64,7 @@ def test_jx106_broken_adjoint_demonstrably_fails(fixture_report):
 
 def test_site_anchored_findings_point_into_the_fixture(fixture_report):
     sited = [f for f in fixture_report.findings
-             if f.rule in ("JX101", "JX103", "JX104")]
+             if f.rule in ("JX101", "JX104")]
     assert sited
     for f in sited:
         assert f.path == "tests/jaxlint_fixtures/jaxpr_bad.py"
@@ -109,9 +112,16 @@ def test_repo_jx_baseline_entries_are_not_stale(repo_report):
 
 
 def test_known_suppressions_are_exercised(repo_report):
-    """The two vetted suppressions this tier ships with stay live: the
-    segment-core streak carry (baseline) and the NaN-marker device_put
-    (pragma). If either stops firing, the suppression must be removed."""
-    hows = {(f.rule, how) for f, how in repo_report.suppressed}
-    assert ("JX103", "baseline") in hows
-    assert ("JX104", "pragma") in hows
+    """Every inline JX pragma under ``src/`` suppresses a live finding: a
+    pragma whose finding stopped firing must be removed."""
+    pragma_sites = set()
+    for dirpath, _, files in os.walk(os.path.join(REPO, "src")):
+        for name in files:
+            if not name.endswith(".py"):
+                continue
+            path = os.path.join(dirpath, name)
+            with open(path, encoding="utf-8") as f:
+                if any(_JX_PRAGMA.search(line) for line in f):
+                    pragma_sites.add(os.path.relpath(path, REPO))
+    live = {f.path for f, how in repo_report.suppressed if how == "pragma"}
+    assert pragma_sites <= live, f"stale JX pragmas in {pragma_sites - live}"

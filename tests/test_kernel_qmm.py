@@ -6,7 +6,7 @@ import numpy as np
 import pytest
 from hypothesis_shim import given, settings, st
 
-from repro.kernels.qmm.kernel import select_block_config
+from repro.kernels.qmm.kernel import VMEM_BUDGET_BYTES, select_block_config, vmem_bytes
 from repro.kernels.qmm.ops import (
     pack_operator,
     pack_weights,
@@ -234,3 +234,15 @@ class TestSelectBlockConfig:
     def test_explicit_aligned_block_accepted(self):
         assert select_block_config(128, 256, 1024, 8, block_m=64,
                                    block_n=128, block_k=512) == (64, 128, 512)
+
+    @pytest.mark.parametrize("bits,group_size", [(2, None), (4, 16), (8, 64)])
+    def test_auto_fits_vmem_budget(self, bits, group_size):
+        # a 128-row batch: the group kernel's expanded x tile would need
+        # ~14 MB at the default blocks (g = 16), so the K step shrinks to fit
+        blocks = select_block_config(128, 65536, 65536, bits, group_size=group_size)
+        assert vmem_bytes(*blocks, bits, group_size) <= VMEM_BUDGET_BYTES
+
+    def test_explicit_block_over_vmem_budget_raises(self):
+        with pytest.raises(ValueError, match="VMEM"):
+            select_block_config(128, 1024, 65536, 4, group_size=16,
+                                block_m=128, block_n=1024, block_k=4096)

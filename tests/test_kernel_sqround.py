@@ -29,6 +29,15 @@ class TestSqroundVsOracle:
         assert float(s_pal) == float(s_ref)
 
     @pytest.mark.parametrize("bits", BITS)
+    def test_bit_exact_across_padded_tiles(self, bits):
+        # wider and taller than one (256, 512) tile: both axes are padded
+        key = jax.random.PRNGKey(7)
+        v = jax.random.normal(key, (300, 600), jnp.float32) * 3.0
+        c_pal, _ = sqround(v, bits, key, use_pallas=True, interpret=True)
+        c_ref, _ = sqround(v, bits, key, use_pallas=False)
+        np.testing.assert_array_equal(np.asarray(c_pal), np.asarray(c_ref))
+
+    @pytest.mark.parametrize("bits", BITS)
     def test_codes_in_range(self, bits):
         key = jax.random.PRNGKey(1)
         v = jax.random.normal(key, (64, 64), jnp.float32)

@@ -135,9 +135,7 @@ class TestDtypeRoundTrip:
 
     @pytest.mark.parametrize("dt", ["float32", "float64", "complex64", "complex128"])
     def test_fake_quantize_preserves_dtype(self, dt):
-        from jax.experimental import enable_x64
-
-        with enable_x64():
+        with jax.enable_x64():
             dtype = jnp.dtype(dt)
             v = jnp.asarray([0.5, -0.25, 1.0, 0.0], dtype)
             if jnp.issubdtype(dtype, jnp.complexfloating):
@@ -150,9 +148,7 @@ class TestDtypeRoundTrip:
 
     def test_complex128_explicit_f32_scale(self):
         """The narrowing path: an f32 scale must not drag the output to c64."""
-        from jax.experimental import enable_x64
-
-        with enable_x64():
+        with jax.enable_x64():
             v = jnp.asarray([0.5 + 0.5j, -0.25 - 1.0j], jnp.complex128)
             q = quantize(v, 8, jax.random.PRNGKey(1), scale=jnp.float32(1.0))
             assert q.dequantize(jnp.complex128).dtype == jnp.complex128

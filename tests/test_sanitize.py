@@ -75,6 +75,20 @@ def test_sanitize_trips_on_inf():
              ).block_until_ready()
 
 
+def test_sanitize_trips_on_cached_op_after_local_scope():
+    # a jitted jnp op warmed with the checks off (a shape unique in the
+    # suite, so no earlier test compiled it), then a thread-local
+    # debug_nans(False) scope (JAX opens one in core.jaxpr_as_fun): the
+    # tripwire must still fire on the op's cached dispatch path
+    a, b = jnp.full((47,), 2.0, jnp.float32), jnp.zeros((47,), jnp.float32)
+    (a / b).block_until_ready()
+    with jax.debug_nans(False):
+        pass
+    with sanitize():
+        with pytest.raises(FloatingPointError):
+            (a / b).block_until_ready()
+
+
 def test_sanitize_restores_flags():
     before = (jax.config.jax_debug_nans, jax.config.jax_debug_infs)
     with sanitize():

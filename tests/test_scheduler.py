@@ -547,7 +547,9 @@ def test_scheduler_multidevice_parity_subprocess():
     root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     env["PYTHONPATH"] = os.path.join(root, "src") + os.pathsep + env.get(
         "PYTHONPATH", "")
-    env.pop("JAX_PLATFORMS", None)
+    # the forced host devices are CPU devices: never let the child reach for
+    # an accelerator this process (or another) may hold
+    env["JAX_PLATFORMS"] = "cpu"
     res = subprocess.run([sys.executable, "-c", _MULTIDEV_SCRIPT], env=env,
                          cwd=root, capture_output=True, text=True, timeout=600)
     assert res.returncode == 0, res.stderr[-2000:]
