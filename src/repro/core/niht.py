@@ -75,8 +75,15 @@ class IHTTrace(NamedTuple):
 
 
 class IHTResult(NamedTuple):
+    """``x`` and the per-iteration ``trace``. ``iters_run`` (int32, one per
+    row; a scalar from :func:`qniht`) counts the iterations the loop executed
+    for the shard that solved the row: ``n_iters`` without early exit, the
+    trip count of the early-exit loop with it. It stays on the device until a
+    caller reads it."""
+
     x: jax.Array
     trace: IHTTrace
+    iters_run: Optional[jax.Array] = None
 
 
 # consecutive sub-tol updates required before the early-exit freeze rule
@@ -132,28 +139,39 @@ def _niht_iteration_batch(
     Returns (X_new, mu, changed, n_backtracks), all leading-axis B.
     """
     eps = jnp.asarray(1e-30, jnp.float32)
-    R = Yhat - op2.mv(X)
-    G = op1.rmv(R)
+    # named scopes (mv, rmv, threshold, backtrack) only label the lowered
+    # ops' metadata, so a profiler trace can attribute device time to them
+    with jax.named_scope("mv"):
+        PX = op2.mv(X)
+    R = Yhat - PX
+    with jax.named_scope("rmv"):
+        G = op1.rmv(R)
 
     # Γ: support of x, or (first iteration, x = 0) the top-s of the first gradient.
     on_init = _rows_sqnorm(X) == 0.0
     mask_x = jnp.abs(X) > 0
-    mask_g = jax.vmap(lambda g: top_s_mask(g, s))(G)
+    with jax.named_scope("threshold"):
+        mask_g = jax.vmap(lambda g: top_s_mask(g, s))(G)
     gamma = jnp.where(on_init[:, None], mask_g, mask_x)
 
     Gg = jnp.where(gamma, G, jnp.zeros_like(G))
-    mu0 = _rows_sqnorm(Gg) / (_rows_sqnorm(op1.mv(Gg)) + eps)
+    with jax.named_scope("mv"):
+        PGg = op1.mv(Gg)
+    mu0 = _rows_sqnorm(Gg) / (_rows_sqnorm(PGg) + eps)
 
     def propose(mu):
         A = X.astype(G.dtype) + mu[:, None] * G
         A = _project(A, real_signal, nonneg).astype(X.dtype)
-        return hs(A)
+        with jax.named_scope("threshold"):
+            return hs(A)
 
     def accept(mu, Xp):
         new_mask = jnp.abs(Xp) > 0
         same = jnp.all(new_mask == gamma, axis=-1)
         D = Xp - X
-        omega = _rows_sqnorm(D) / (_rows_sqnorm(op1.mv(D)) + eps)
+        with jax.named_scope("mv"):
+            PD = op1.mv(D)
+        omega = _rows_sqnorm(D) / (_rows_sqnorm(PD) + eps)
         return same | (mu <= (1.0 - c) * omega)
 
     X0 = propose(mu0)
@@ -173,7 +191,8 @@ def _niht_iteration_batch(
         still_rejected = act & ~accept(mu_new, Xp_new)
         return mu_new, Xp_new, nbt_new, still_rejected
 
-    mu, X_new, n_bt, _ = jax.lax.while_loop(cond, body, (mu0, X0, nbt0, active0))
+    with jax.named_scope("backtrack"):
+        mu, X_new, n_bt, _ = jax.lax.while_loop(cond, body, (mu0, X0, nbt0, active0))
     changed = ~jnp.all((jnp.abs(X_new) > 0) == gamma, axis=-1)
     return X_new, mu, changed, n_bt
 
@@ -293,8 +312,10 @@ def _solver_setup(
             real_signal, nonneg, hs,
         )
         if with_trace:
-            rq = jnp.sqrt(_rows_sqnorm(Yhat - op2.mv(X_new)))
-            rt = jnp.sqrt(_rows_sqnorm(Y - phi_true.mv(X_new)))
+            with jax.named_scope("mv"):
+                PX, TX = op2.mv(X_new), phi_true.mv(X_new)
+            rq = jnp.sqrt(_rows_sqnorm(Yhat - PX))
+            rt = jnp.sqrt(_rows_sqnorm(Y - TX))
         else:
             # skip the residual matvecs (one of them streams dense f32 Φ —
             # benchmarks disable the trace so the loop is pure algorithm traffic)
@@ -365,6 +386,7 @@ def _qniht_core(
     if not early_exit:
         X_final, (rq, rt, mus, ch, bt) = jax.lax.scan(
             lambda X, i: iteration(X, i), X0, jnp.arange(n_iters), unroll=unroll)
+        k_end = jnp.asarray(n_iters, jnp.int32)
     else:
         # A while_loop, NOT a scan-of-cond: under SPMD partitioning
         # (shard_map) XLA rewrites a cond into a select that executes BOTH
@@ -426,6 +448,7 @@ def _qniht_core(
     return IHTResult(
         x=X_final,
         trace=IHTTrace(resid_q=rq, resid_true=rt, mu=mus, support_changed=ch, backtracks=bt),
+        iters_run=jnp.full((B,), k_end, jnp.int32),
     )
 
 
@@ -644,12 +667,15 @@ def solver_result(state: SolverState) -> IHTResult:
 
     Trace rows at iterations ``>= state.k`` (a run finalized before the
     horizon — e.g. a preempted partial result) are filled with the stationary
-    last row, matching the early-exit tail-fill convention."""
+    last row, matching the early-exit tail-fill convention. ``iters_run`` is
+    ``state.k`` for every row: the iterations the segments advanced, those an
+    early exit filled included."""
     n_iters = state.trace.mu.shape[0]
     tail = jnp.arange(n_iters)[:, None] >= state.k
     trace = jax.tree_util.tree_map(
         lambda buf, o: jnp.where(tail, o[None, :], buf), state.trace, state.last)
-    return IHTResult(x=state.X, trace=trace)
+    return IHTResult(x=state.X, trace=trace,
+                     iters_run=jnp.full(state.X.shape[:1], state.k, jnp.int32))
 
 
 _STATIC = (
@@ -733,6 +759,7 @@ def qniht(
     return IHTResult(
         x=res.x[0],
         trace=jax.tree_util.tree_map(lambda t: t[:, 0], res.trace),
+        iters_run=res.iters_run[0],
     )
 
 

@@ -309,17 +309,23 @@ class SubsampledFourierOperator:
         bare Fourier operator or a composition uniformly."""
         return self
 
+    # named scopes "fft" and "sample" label the transform and the k-space
+    # gather/scatter in a profiler trace (metadata only)
     def mv(self, x: jax.Array) -> jax.Array:
         r = self.resolution
         img = x.reshape(*x.shape[:-1], r, r)
-        k = jnp.fft.fft2(img, norm="ortho").astype(self.dtype)
-        return jnp.take(k.reshape(*x.shape[:-1], r * r), self.indices, axis=-1)
+        with jax.named_scope("fft"):
+            k = jnp.fft.fft2(img, norm="ortho").astype(self.dtype)
+        with jax.named_scope("sample"):
+            return jnp.take(k.reshape(*x.shape[:-1], r * r), self.indices, axis=-1)
 
     def rmv(self, v: jax.Array) -> jax.Array:
         r = self.resolution
-        full = jnp.zeros((*v.shape[:-1], r * r), jnp.complex64)
-        full = full.at[..., self.indices].set(v.astype(self.dtype))
-        img = jnp.fft.ifft2(full.reshape(*v.shape[:-1], r, r), norm="ortho")
+        with jax.named_scope("sample"):
+            full = jnp.zeros((*v.shape[:-1], r * r), jnp.complex64)
+            full = full.at[..., self.indices].set(v.astype(self.dtype))
+        with jax.named_scope("fft"):
+            img = jnp.fft.ifft2(full.reshape(*v.shape[:-1], r, r), norm="ortho")
         return img.reshape(*v.shape[:-1], r * r).astype(self.dtype)
 
     def tree_flatten(self):
@@ -375,14 +381,16 @@ class WaveletSynthesisOperator:
         from repro.transforms.wavelet import idwt2
 
         r = self.resolution
-        img = idwt2(c.reshape(*c.shape[:-1], r, r), self.wavelet, self.levels)
+        with jax.named_scope("dwt"):
+            img = idwt2(c.reshape(*c.shape[:-1], r, r), self.wavelet, self.levels)
         return img.reshape(*c.shape[:-1], r * r)
 
     def rmv(self, x: jax.Array) -> jax.Array:
         from repro.transforms.wavelet import dwt2
 
         r = self.resolution
-        co = dwt2(x.reshape(*x.shape[:-1], r, r), self.wavelet, self.levels)
+        with jax.named_scope("dwt"):
+            co = dwt2(x.reshape(*x.shape[:-1], r, r), self.wavelet, self.levels)
         return co.reshape(*x.shape[:-1], r * r)
 
     def tree_flatten(self):

@@ -164,11 +164,14 @@ def qmm(
                                      block_m=block_m, block_n=block_n,
                                      block_k=block_k)
     mp, np_, kp = _round_up(m, bm), _round_up(n, bn), _round_up(w.k_dim, bk)
-    x_p = jnp.pad(x, ((0, mp - m), (0, kp - k)))
     packed_k = kp // vpb
-    w_p = jnp.pad(w.packed, ((0, np_ - n), (0, packed_k - w.packed.shape[1])),
-                  constant_values=_zero_byte(w.bits))
-    s_p = jnp.pad(w.scale, ((0, 0), (0, np_ - n)))
+    # a named scope on the per-call copies of x, the codes and the scales,
+    # so a profiler trace can tell them from the kernel
+    with jax.named_scope("qmm_pad"):
+        x_p = jnp.pad(x, ((0, mp - m), (0, kp - k)))
+        w_p = jnp.pad(w.packed, ((0, np_ - n), (0, packed_k - w.packed.shape[1])),
+                      constant_values=_zero_byte(w.bits))
+        s_p = jnp.pad(w.scale, ((0, 0), (0, np_ - n)))
     y = qmm_pallas(x_p, w_p, s_p, bits=w.bits, k_dim=kp,
                    block_m=bm, block_n=bn, block_k=bk, interpret=interpret)
     return y[:m, :n]
@@ -186,11 +189,12 @@ def _qmm_group(x, w: PackedWeights, interpret, block_m, block_n, block_k):
                                      block_m=block_m, block_n=block_n,
                                      block_k=block_k)
     mp, np_, kp = _round_up(m, bm), _round_up(n, bn), _round_up(w.k_dim, bk)
-    x_p = jnp.pad(x, ((0, mp - m), (0, kp - k)))
-    w_p = jnp.pad(w.packed, ((0, np_ - n), (0, kp // vpb - w.packed.shape[1])),
-                  constant_values=_zero_byte(w.bits))
-    s_p = jnp.pad(w.scale, ((0, np_ - n), (0, kp // g - w.scale.shape[1])),
-                  constant_values=1.0)
+    with jax.named_scope("qmm_pad"):
+        x_p = jnp.pad(x, ((0, mp - m), (0, kp - k)))
+        w_p = jnp.pad(w.packed, ((0, np_ - n), (0, kp // vpb - w.packed.shape[1])),
+                      constant_values=_zero_byte(w.bits))
+        s_p = jnp.pad(w.scale, ((0, np_ - n), (0, kp // g - w.scale.shape[1])),
+                      constant_values=1.0)
     y = qmm_group_pallas(x_p, w_p, s_p, bits=w.bits, k_dim=kp, group_size=g,
                          block_m=bm, block_n=bn, block_k=bk, interpret=interpret)
     return y[:m, :n]

@@ -88,10 +88,12 @@ def force_host_devices(n: int, env=None) -> None:
 _CORE_STATICS = _STATIC
 
 # x is (B_local, N) → rows sharded; trace arrays are (n_iters, B_local) → the
-# batch axis is second. The operator/key inputs are replicated (P() prefix).
+# batch axis is second; iters_run is (B_local,), each shard's own trip count
+# repeated on its rows. The operator/key inputs are replicated (P() prefix).
 _OUT_SPECS = IHTResult(
     x=P(BATCH_AXIS),
     trace=IHTTrace(*([P(None, BATCH_AXIS)] * 5)),
+    iters_run=P(BATCH_AXIS),
 )
 
 
@@ -169,6 +171,7 @@ def sharded_qniht_run(phi, Y, key, *, mesh=None, n_devices=None, **statics) -> I
     return IHTResult(
         x=res.x[:b],
         trace=jax.tree_util.tree_map(lambda t: t[:, :b], res.trace),
+        iters_run=res.iters_run[:b],
     )
 
 
@@ -385,9 +388,9 @@ class BatchServer:
     **drains** already-completed chunks from disk (their solve is skipped;
     ``n_drained`` counts them) and **replays** in-flight ones; the resulting
     ``x`` stream is bit-identical to the uninterrupted run. Drained chunks
-    carry a NaN/zero placeholder trace (the journal persists ``x``, the
-    serving product — traces are diagnostics; re-run without a kill if you
-    need them).
+    carry a NaN/zero placeholder trace and ``iters_run`` of zeros (the journal
+    persists ``x``, the serving product — traces are diagnostics; re-run
+    without a kill if you need them).
     """
 
     def __init__(self, phi, s: int, n_iters: int = 50, *, mesh=None,
@@ -468,7 +471,8 @@ class BatchServer:
                 self.journal.verify_submit(idx, Y, k, mask)
                 self.n_drained += 1
                 return IHTResult(x=jnp.asarray(self.journal.load_result_full(idx)),
-                                 trace=self._placeholder_trace(Y.shape[0]))
+                                 trace=self._placeholder_trace(Y.shape[0]),
+                                 iters_run=jnp.zeros((Y.shape[0],), jnp.int32))
             self.journal.record_submit(idx, Y, k, mask)
         self._shapes.add(Y.shape)
         res = sharded_qniht_run(self.phi, Y, k, mesh=self.mesh, **self._statics)
