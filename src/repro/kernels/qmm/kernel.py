@@ -21,13 +21,28 @@ kernel never does it: the wrappers permute ``x``'s columns once per call
 ``vpb`` part dots. The packed format itself is unchanged (one format for the CPU
 fused path, the oracle and this kernel).
 
+One grid step, in full float32 with one MXU pass: codes of ≤ 8 bits are
+integers with ``|c| ≤ 255``, which bfloat16 holds exactly, so the step unpacks
+them straight to bf16. The f32 x tile is split exactly into three bf16 parts,
+``x = hi + mid + lo`` (:func:`split_bf16x3`), stacked as one ``(3·bm, bk)``
+LHS; one bf16 dot with f32 accumulation forms ``Σ c·hi``, ``Σ c·mid`` and
+``Σ c·lo`` — every product exact in f32 — and the three ``(bm, bn)`` row blocks
+are summed in f32. These are the products a ``Precision.HIGHEST`` dot forms
+when one operand's low bf16 pieces are zero, without its extra MXU passes or
+the per-pass VPU split of the code tile. The step does this in a loop over
+chunks of at most 512 packed lanes (:func:`_chunk_lanes`), so the kernel's
+code, which the TPU holds in HBM, does not grow with the step.
+
 Grid: ``(M/bm, N/bn, K/bk)``; K is the fastest-varying (sequential on TPU), and
 the output block (bm, bn) is revisited across the K steps and accumulated in
-place (initialized at k==0). Block shapes are chosen by
-:func:`select_block_config`: MXU-aligned ``bm=128, bn=128, bk=512`` for large
-problems, clamped down to the aligned problem size for small ones so tiny
-shapes (the recovery benchmarks run m=64, n=128) are not dwarfed by padding,
-and shrunk until the step's VMEM estimate (:func:`vmem_bytes`) fits the budget.
+place (initialized at k==0). Every grid step costs a fixed ~0.24 µs on a TPU
+v5e besides its stream, so :func:`select_block_config` sizes the tiles against a
+model of one call: the grid steps' fixed cost, the codes streamed (padding
+included; once per M block), x streamed (once per N block) and the first and
+last steps, which the pipeline cannot overlap — under the step's VMEM estimate
+(:func:`vmem_bytes`), which the kernel requests from the compiler
+(:data:`VMEM_LIMIT_BYTES`). Tiles are multiples of the hardware minima, at most
+the aligned problem, so a K of 870 pads to 896, not to a multiple of 512.
 Explicitly passed block shapes are validated strictly — misalignment, a block
 that pads the problem more than the hardware minima require, or one over the
 VMEM budget raises instead of failing in the TPU compiler.
@@ -40,10 +55,11 @@ Two scale layouts, two kernels:
 * :func:`qmm_group_pallas` — blockwise scales along the contraction axis,
   shape (N, K/g), streamed transposed as (K/g, N) so each K-tile's scale slab
   ``(bk/g, bn)`` is a legal (8, 128)-tiled block. The scale varies within the
-  contraction, so it cannot multiply the accumulator directly; instead the x
-  tile is expanded to one row block per scale group (x masked to that group's
+  contraction, so it cannot multiply the accumulator directly; instead x's
+  parts are expanded to one row block per scale group (masked to that group's
   columns), one dot yields every group's partial sums at once, and each
-  partial is scaled on the output side. ``bk/g`` must be a multiple of 8.
+  partial is scaled on the output side. ``bk/g`` must be a multiple of 8; the
+  expansion grows with ``bk/g``, so this kernel keeps the least K step.
 """
 from __future__ import annotations
 
@@ -53,22 +69,28 @@ import math
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.quant.formats import BY_BITS
 
 # Hardware minima the tiles must respect regardless of problem size: 8
 # sublanes (M), 128 lanes (N), and a K step that is whole packed bytes on
-# 128 lanes (128·vpb values). Defaults below are the MXU sweet spot for
-# large problems; select_block_config clamps them to the aligned problem.
+# 128 lanes (128·vpb values). M blocks hold at most the MXU's 128 rows.
 _MIN_BM = 8
 _MIN_BN = 128
-_DEFAULT_BM = 128
-_DEFAULT_BN = 128
-_DEFAULT_BK = 512
+_MAX_BM = 128
+# Largest N and K tiles the search considers (compile time grows with the
+# tile: the kernel body is unrolled over it).
+_MAX_BN = 4096
+_MAX_BK = 8192
+# Bytes HBM streams (819 GB/s) in the fixed cost of one grid step, about
+# 0.24 µs on a TPU v5e (measured with the codes DMA'd and not computed on).
+_STEP_COST_BYTES = 192 * 1024
 
-# The TPU compiler grants a kernel 16 MiB of scoped VMEM by default (v5e);
+# Scoped VMEM the kernels request from the TPU compiler (v5e has 128 MiB);
 # tiles are sized against half of it so the compiler's own scratch fits too.
-VMEM_BUDGET_BYTES = 8 * 1024 * 1024
+VMEM_LIMIT_BYTES = 64 * 1024 * 1024
+VMEM_BUDGET_BYTES = VMEM_LIMIT_BYTES // 2
 
 
 def _round_up(value: int, multiple: int) -> int:
@@ -78,17 +100,42 @@ def _round_up(value: int, multiple: int) -> int:
 def vmem_bytes(bm: int, bn: int, bk: int, bits: int,
                group_size: int | None = None) -> int:
     """Estimated VMEM bytes of one grid step: double-buffered input and output
-    blocks plus the in-kernel temporaries (unpacked parts, and for the group
-    kernel the group-expanded x tile and its partial sums)."""
+    blocks plus the temporaries of the codes unpacked at once (a chunk of the
+    tile, :func:`_chunk_lanes`, or the whole tile for the group kernel): one
+    part's int32 words, the bf16 codes, x's three parts in f32 and bf16, their
+    products, and for the group kernel the group-expanded parts."""
     vpb = BY_BITS[bits].values_per_byte
     bkp = bk // vpb
     scale_blk = 4 * (max(8, bk // group_size) if group_size else 8) * bn
     blocks = 2 * (4 * bm * bk + bn * bkp + scale_blk) + 2 * 4 * bm * bn
-    temps = 4 * bn * bkp + 2 * 4 * bn * bk      # int32 words + f32 parts
+    rows = 3 * bm * (bk // group_size if group_size else 1)
+    c = bk if group_size else _chunk_lanes(bkp) * vpb
+    temps = 4 * bn * c // vpb + 2 * bn * c + 6 * 3 * bm * c + 4 * rows * bn
     if group_size:
-        rows = (bk // group_size) * bm
-        temps += 4 * rows * bk + 2 * 4 * rows * bn
+        temps += 6 * rows * bkp
     return blocks + temps
+
+
+def _call_cost(m: int, n: int, k_dim: int, bm: int, bn: int, bk: int,
+               vpb: int) -> int:
+    """Modelled cost of one call in streamed bytes: the fixed cost of every
+    grid step, the padded codes (once per M block), x (once per N block), and
+    the first and last steps' blocks, which the pipeline cannot overlap."""
+    mp, np_, kp = _round_up(m, bm), _round_up(n, bn), _round_up(k_dim, bk)
+    steps = (mp // bm) * (np_ // bn) * (kp // bk)
+    streamed = (mp // bm) * np_ * kp // vpb + (np_ // bn) * mp * kp * 4
+    return steps * _STEP_COST_BYTES + streamed + 2 * (bn * bk // vpb + 4 * bm * bk)
+
+
+def _candidates(cap: int, unit: int, limit: int) -> list[int]:
+    """Tiles to try along one axis: the unit's power-of-two multiples and the
+    aligned problem size itself, none above ``limit`` (nor ``cap``)."""
+    out = {cap} if cap <= limit else set()
+    t = unit
+    while t <= min(cap, limit):
+        out.add(t)
+        t *= 2
+    return sorted(out)
 
 
 def select_block_config(
@@ -104,12 +151,13 @@ def select_block_config(
 ) -> tuple[int, int, int]:
     """Choose (bm, bn, bk) for a packed matmul of logical shape (m, k)×(n, k).
 
-    Auto mode (a block dim left ``None``): start from the MXU defaults and
-    clamp each tile to the problem dimension rounded up to its hardware
-    minimum, so small problems (the fig5 bench runs m=64, n=128) pay only the
-    unavoidable alignment padding instead of a full 128×128×512 tile; then
-    shrink the free dims (bk first, then bm, then bn) until
-    :func:`vmem_bytes` fits :data:`VMEM_BUDGET_BYTES`.
+    Auto mode (a block dim left ``None``): ``bm`` is the problem's rows
+    rounded up to 8, at most 128. ``bn`` and ``bk`` are searched over the
+    hardware minimum's power-of-two multiples and the aligned problem size
+    (128 lanes for N; ``128·vpb`` codes for K, lcm'd with ``8·group_size`` for
+    the group kernel, whose K step stays at that unit): the pair whose
+    modelled call cost (:func:`_call_cost`) is least, among those whose
+    :func:`vmem_bytes` fits :data:`VMEM_BUDGET_BYTES`, ties to the smaller tile.
 
     Explicit mode (a block dim passed): validate strictly — misaligned blocks,
     a K step that is not a whole number of 8-row scale slabs (group kernel),
@@ -117,7 +165,8 @@ def select_block_config(
     set over the VMEM budget raise ``ValueError`` instead of silently blowing
     up the padded footprint or failing in the TPU compiler.
     """
-    k_unit = 128 * BY_BITS[bits].values_per_byte
+    vpb = BY_BITS[bits].values_per_byte
+    k_unit = 128 * vpb
     if group_size is not None:
         # the transposed scale slab (bk/g, bn) must tile 8 sublanes
         k_unit = math.lcm(k_unit, 8 * group_size)
@@ -127,7 +176,7 @@ def select_block_config(
     k_cap = _round_up(max(k_dim, 1), k_unit)
 
     if block_m is None:
-        bm = min(_DEFAULT_BM, m_cap)
+        bm = min(_MAX_BM, m_cap)
     else:
         bm = block_m
         if bm % _MIN_BM:
@@ -138,47 +187,49 @@ def select_block_config(
                 "the tile would be mostly padding; shrink it or leave it unset"
             )
     if block_n is None:
-        bn = min(_DEFAULT_BN, n_cap)
+        bns = _candidates(n_cap, _MIN_BN, _MAX_BN)
     else:
-        bn = block_n
-        if bn % _MIN_BN:
-            raise ValueError(f"block_n={bn} must be a multiple of {_MIN_BN}")
-        if bn > n_cap:
+        if block_n % _MIN_BN:
+            raise ValueError(f"block_n={block_n} must be a multiple of {_MIN_BN}")
+        if block_n > n_cap:
             raise ValueError(
-                f"block_n={bn} exceeds aligned problem size {n_cap} (n={n}): "
+                f"block_n={block_n} exceeds aligned problem size {n_cap} (n={n}): "
                 "the tile would be mostly padding; shrink it or leave it unset"
             )
+        bns = [block_n]
     if block_k is None:
-        bk = min(_round_up(_DEFAULT_BK, k_unit), k_cap)
+        bks = [k_unit] if group_size else _candidates(k_cap, k_unit, _MAX_BK)
     else:
-        bk = block_k
-        if bk % k_unit:
+        if block_k % k_unit:
             raise ValueError(
-                f"block_k={bk} must be a multiple of {k_unit} "
+                f"block_k={block_k} must be a multiple of {k_unit} "
                 f"(128 lanes × values/byte at {bits} bits"
                 + (f", lcm 8 × group_size={group_size}" if group_size else "")
                 + ")"
             )
-        if bk > k_cap:
+        if block_k > k_cap:
             raise ValueError(
-                f"block_k={bk} exceeds aligned problem size {k_cap} (k={k_dim}): "
+                f"block_k={block_k} exceeds aligned problem size {k_cap} (k={k_dim}): "
                 "the tile would be mostly padding; shrink it or leave it unset"
             )
+        bks = [block_k]
 
-    while vmem_bytes(bm, bn, bk, bits, group_size) > VMEM_BUDGET_BYTES:
-        if block_k is None and bk > k_unit:
-            bk = _round_up(bk // 2, k_unit)
-        elif block_m is None and bm > _MIN_BM:
+    while True:
+        fits = [(_call_cost(m_cap, n, k_dim, bm, bn, bk, vpb), bn * bk, bn, bk)
+                for bn in bns for bk in bks
+                if vmem_bytes(bm, bn, bk, bits, group_size) <= VMEM_BUDGET_BYTES]
+        if fits:
+            _, _, bn, bk = min(fits)
+            return bm, bn, bk
+        if block_m is None and bm > _MIN_BM:
             bm = _round_up(bm // 2, _MIN_BM)
-        elif block_n is None and bn > _MIN_BN:
-            bn = _round_up(bn // 2, _MIN_BN)
-        else:
-            raise ValueError(
-                f"blocks ({bm}, {bn}, {bk}) at {bits} bits need "
-                f"~{vmem_bytes(bm, bn, bk, bits, group_size)} B of VMEM, over "
-                f"the {VMEM_BUDGET_BYTES} B budget; shrink them or leave them "
-                "unset")
-    return bm, bn, bk
+            continue
+        bn, bk = bns[0], bks[0]
+        raise ValueError(
+            f"blocks ({bm}, {bn}, {bk}) at {bits} bits need "
+            f"~{vmem_bytes(bm, bn, bk, bits, group_size)} B of VMEM, over "
+            f"the {VMEM_BUDGET_BYTES} B budget; shrink them or leave them "
+            "unset")
 
 
 def planar_x(x: jax.Array, bits: int, block_k: int) -> jax.Array:
@@ -194,25 +245,53 @@ def planar_x(x: jax.Array, bits: int, block_k: int) -> jax.Array:
             .swapaxes(2, 3).reshape(m, k))
 
 
+def _bf16(v: jnp.ndarray) -> jnp.ndarray:
+    """Cast an MXU operand to bf16: codes and x's split parts are exact in it,
+    and where the split rounds x, its later parts carry what was dropped."""
+    # jaxlint: allow=JL001 -- see the docstring: the parts lose nothing of x
+    return v.astype(jnp.bfloat16)
+
+
+def split_bf16x3(x: jnp.ndarray) -> jnp.ndarray:
+    """f32 (r, c) → (3r, c) f32 rows ``[hi; mid; lo]``, each exactly a bf16,
+    with ``hi + mid + lo == x`` exactly: each part takes the next 8 significant
+    bits of what the parts before it left, and f32 holds 24. Exact for 0 and
+    for every ``2^-100 ≤ |x| < 2^127`` (no part underflows or overflows)."""
+    hi = _bf16(x).astype(jnp.float32)
+    rest = x - hi
+    mid = _bf16(rest).astype(jnp.float32)
+    return jnp.concatenate([hi, mid, rest - mid], axis=0)
+
+
 def _unpack_parts(w_packed_blk: jnp.ndarray, bits: int) -> list[jnp.ndarray]:
-    """(bn, bkp) uint8 → vpb lane-dense (bn, bkp) f32 unit-scale code blocks;
-    part ``i`` holds the codes ``j·vpb + i`` of the tile."""
+    """(bn, bkp) uint8 → vpb lane-dense (bn, bkp) bf16 unit-scale code blocks
+    (exact: every code is an integer of at most 8 bits); part ``i`` holds the
+    codes ``j·vpb + i`` of the tile."""
     fmt = BY_BITS[bits]
     k = fmt.half_steps
     w32 = w_packed_blk.astype(jnp.int32)
     if fmt.values_per_byte == 1:
-        return [(w32 - k).astype(jnp.float32)]
-    mask = (1 << bits) - 1
-    return [(((w32 >> (bits * i)) & mask) - k).astype(jnp.float32)
-            for i in range(fmt.values_per_byte)]
+        fields = [w32]
+    else:
+        mask = (1 << bits) - 1
+        fields = [(w32 >> (bits * i)) & mask for i in range(fmt.values_per_byte)]
+    return [_bf16((f - k).astype(jnp.float32)) for f in fields]
 
 
 def _dot_nt(a, b):
-    """(r, c) × (n, c) → (r, n), contracting the lane axis of both, in full
-    f32 (the MXU default would round the f32 x operand to bf16)."""
+    """(r, c) × (n, c) → (r, n), contracting the lane axis of both: one MXU
+    pass over bf16 operands, products and accumulation in f32."""
     return jax.lax.dot_general(
-        a, b, (((1,), (1,)), ((), ())), precision=jax.lax.Precision.HIGHEST,
-        preferred_element_type=jnp.float32)
+        a, b, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32)
+
+
+def _chunk_lanes(bkp: int) -> int:
+    """Packed lanes of the code tile that one iteration of :func:`_qmm_kernel`
+    unpacks and multiplies: the largest multiple of 128 lanes, at most 512,
+    that divides the tile's ``bkp``. The loop keeps the kernel's code (which
+    the TPU holds in HBM beside the operator) and its temporaries the size of
+    one chunk, whatever the grid step streams."""
+    return max(c for c in range(128, min(bkp, 512) + 1, 128) if bkp % c == 0)
 
 
 def _qmm_kernel(x_ref, w_ref, scale_ref, o_ref, *, bits: int):
@@ -220,14 +299,24 @@ def _qmm_kernel(x_ref, w_ref, scale_ref, o_ref, *, bits: int):
     def _init():
         o_ref[...] = jnp.zeros_like(o_ref)
 
-    parts = _unpack_parts(w_ref[...], bits)             # vpb × (bn, bkp)
-    bkp = parts[0].shape[1]
-    acc = None
-    for i, part in enumerate(parts):
-        x_i = x_ref[:, i * bkp:(i + 1) * bkp].astype(jnp.float32)   # (bm, bkp)
-        d = _dot_nt(x_i, part)
-        acc = d if acc is None else acc + d
-    o_ref[...] += acc * (scale_ref[...] / BY_BITS[bits].half_steps)  # (1, bn) bcast
+    bm = x_ref.shape[0]
+    bkp = w_ref.shape[1]
+    cp = _chunk_lanes(bkp)
+    scale = scale_ref[...] / BY_BITS[bits].half_steps     # (1, bn)
+
+    def chunk(j, carry):
+        off = pl.multiple_of(j * cp, cp)
+        parts = _unpack_parts(w_ref[:, pl.ds(off, cp)], bits)   # vpb × (bn, cp)
+        acc = None
+        for i, part in enumerate(parts):
+            x_i = x_ref[:, pl.ds(pl.multiple_of(i * bkp + off, cp), cp)]
+            xs = _bf16(split_bf16x3(x_i.astype(jnp.float32)))   # (3·bm, cp)
+            d = _dot_nt(xs, part)                              # (3·bm, bn)
+            acc = d if acc is None else acc + d
+        o_ref[...] += (acc[:bm] + acc[bm:2 * bm] + acc[2 * bm:]) * scale
+        return carry
+
+    jax.lax.fori_loop(0, bkp // cp, chunk, 0)
 
 
 def _qmm_group_kernel(x_ref, w_ref, scale_ref, o_ref, *, bits: int, group_size: int):
@@ -240,6 +329,7 @@ def _qmm_group_kernel(x_ref, w_ref, scale_ref, o_ref, *, bits: int, group_size: 
     scales = scale_ref[...]                             # (bkg, bn), transposed
     bkg, bn = scales.shape
     bm = x_ref.shape[0]
+    xs = split_bf16x3(x_ref[...].astype(jnp.float32))   # (3·bm, bk) f32
     # within every part, column j belongs to scale group j // (g / vpb)
     gp = group_size // BY_BITS[bits].values_per_byte
     grp = jax.lax.broadcasted_iota(jnp.int32, (bkg, 1, bkp), 0) * gp
@@ -247,13 +337,17 @@ def _qmm_group_kernel(x_ref, w_ref, scale_ref, o_ref, *, bits: int, group_size: 
     in_group = (col >= grp) & (col < grp + gp)
     acc = None
     for i, part in enumerate(parts):
-        x_i = x_ref[:, i * bkp:(i + 1) * bkp].astype(jnp.float32)   # (bm, bkp)
-        # row block q of x_g is x restricted to scale group q's columns
-        x_g = jnp.where(in_group, x_i[None], 0.0).reshape(bkg * bm, bkp)
-        d = _dot_nt(x_g, part)                          # (bkg·bm, bn) partials
+        x_i = xs[:, i * bkp:(i + 1) * bkp]              # (3·bm, bkp)
+        # row block q of x_g is x's parts restricted to scale group q's columns
+        x_g = jnp.where(in_group, x_i[None], 0.0).reshape(bkg * 3 * bm, bkp)
+        d = _dot_nt(_bf16(x_g), part)                   # (bkg·3·bm, bn) partials
         acc = d if acc is None else acc + d
-    scaled = acc.reshape(bkg, bm, bn) * scales[:, None, :]
+    acc = acc.reshape(bkg, 3, bm, bn)
+    scaled = (acc[:, 0] + acc[:, 1] + acc[:, 2]) * scales[:, None, :]
     o_ref[...] += jnp.sum(scaled, axis=0) * (1.0 / BY_BITS[bits].half_steps)
+
+
+_COMPILER_PARAMS = pltpu.CompilerParams(vmem_limit_bytes=VMEM_LIMIT_BYTES)
 
 
 @functools.partial(
@@ -305,6 +399,7 @@ def qmm_group_pallas(
         ],
         out_specs=pl.BlockSpec((block_m, block_n), lambda i, j, kk: (i, j)),
         out_shape=jax.ShapeDtypeStruct((m, n), jnp.float32),
+        compiler_params=_COMPILER_PARAMS,
         interpret=interpret,
     )(planar_x(x, bits, block_k), w_packed, scale.T)
 
@@ -349,5 +444,6 @@ def qmm_pallas(
         ],
         out_specs=pl.BlockSpec((block_m, block_n), lambda i, j, kk: (i, j)),
         out_shape=jax.ShapeDtypeStruct((m, n), jnp.float32),
+        compiler_params=_COMPILER_PARAMS,
         interpret=interpret,
     )(planar_x(x, bits, block_k), w_packed, scale)

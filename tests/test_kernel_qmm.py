@@ -1,13 +1,20 @@
 """Pallas qmm kernel: interpret-mode allclose sweeps against the ref.py oracle,
-plus the fused CPU path's parity sweep and the block-config selector rules."""
+its exactness against a float64 reference, the fused CPU path's parity sweep
+and the block-config selector rules."""
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 from hypothesis_shim import given, settings, st
 
-from repro.kernels.qmm.kernel import VMEM_BUDGET_BYTES, select_block_config, vmem_bytes
+from repro.kernels.qmm.kernel import (
+    VMEM_BUDGET_BYTES,
+    select_block_config,
+    split_bf16x3,
+    vmem_bytes,
+)
 from repro.kernels.qmm.ops import (
+    PackedWeights,
     pack_operator,
     pack_weights,
     packed_matvec,
@@ -16,6 +23,8 @@ from repro.kernels.qmm.ops import (
 )
 from repro.kernels.qmm.ref import qmm_group_ref, qmm_ref
 from repro.quant import fake_quantize
+from repro.quant.formats import BY_BITS, PER_CHANNEL, per_block
+from repro.quant.pack import pack_codes
 
 BITS = [2, 4, 8]
 
@@ -60,6 +69,80 @@ class TestQmmVsOracle:
         ref = qmm_ref(x, pw.packed, pw.scale, bits, 512)
         out = qmm(x, pw, use_pallas=True, interpret=True)
         np.testing.assert_allclose(np.asarray(out), np.asarray(ref), rtol=1e-5, atol=1e-4)
+
+
+def _wide_f32(rng, shape, binades=40):
+    """f32 values with random signs whose magnitudes span ``binades`` powers of 2."""
+    mag = np.exp2(rng.uniform(-binades / 2, binades / 2, shape))
+    return (rng.choice([-1.0, 1.0], shape) * rng.uniform(1, 2, shape) * mag
+            ).astype(np.float32)
+
+
+class TestExactness:
+    """The kernel's one bf16 MXU pass is full f32 arithmetic: codes are exact
+    in bf16, x = hi + mid + lo exactly, every product is exact and every sum
+    accumulates in f32."""
+
+    def test_split_reconstructs_every_binade(self):
+        rng = np.random.default_rng(0)
+        # every exponent of the documented range, random mantissas and signs
+        expo = np.repeat(np.arange(-100, 127), 384)
+        mant = rng.integers(0, 1 << 23, expo.size)
+        x = (rng.choice([-1.0, 1.0], expo.size)
+             * np.ldexp(1.0 + mant / 2.0 ** 23, expo)).astype(np.float32)
+        x = np.concatenate([x, np.zeros(128, np.float32)]).reshape(-1, 128)
+        parts = np.asarray(split_bf16x3(jnp.asarray(x, dtype=jnp.float32)))
+        # each part is a bfloat16 exactly: the low 16 bits of its f32 are zero
+        assert not (parts.view(np.uint32) & 0xFFFF).any()
+        r = x.shape[0]
+        hi, mid, lo = parts[:r], parts[r:2 * r], parts[2 * r:]
+        recon = hi.astype(np.float64) + mid.astype(np.float64) + lo.astype(np.float64)
+        np.testing.assert_array_equal(recon, x.astype(np.float64))
+
+    @given(xs=st.lists(st.one_of(
+        st.just(0.0),
+        st.floats(min_value=2.0 ** -100, max_value=2.0 ** 126, width=32),
+        st.floats(min_value=-(2.0 ** 126), max_value=-(2.0 ** -100), width=32)),
+        min_size=1, max_size=64))
+    @settings(max_examples=200, deadline=None)
+    def test_split_reconstructs_any_f32(self, xs):
+        x = np.asarray(xs, np.float32).reshape(1, -1)
+        parts = np.asarray(split_bf16x3(jnp.asarray(x, dtype=jnp.float32)))
+        assert not (parts.view(np.uint32) & 0xFFFF).any()
+        parts = parts.astype(np.float64)
+        np.testing.assert_array_equal(parts.sum(axis=0), x[0].astype(np.float64))
+
+    # explicit blocks: several K steps; or K steps of two 512-lane chunks of
+    # packed codes each (bit planes and chunk offsets must pair up)
+    @pytest.mark.parametrize("blocks", ["auto", "ksteps", "chunks"])
+    @pytest.mark.parametrize("gran", ["per_channel", "per_block"])
+    @pytest.mark.parametrize("bits", BITS)
+    def test_kernel_at_f32_accumulation_level(self, bits, gran, blocks):
+        rng = np.random.default_rng(bits)
+        fmt = BY_BITS[bits]
+        m, n, k, g = 8, 256, 4096, 64
+        codes = rng.integers(fmt.code_min, fmt.code_max + 1, (n, k))
+        codes[0, :2] = fmt.code_min, fmt.code_max          # both ends of the range
+        x = _wide_f32(rng, (m, k))
+        if gran == "per_block":
+            scale = np.exp2(rng.uniform(-4, 4, (n, k // g))).astype(np.float32)
+            w_scale = np.repeat(scale, g, axis=1)
+            granularity = per_block(g)
+        else:
+            scale = np.exp2(rng.uniform(-4, 4, (1, n))).astype(np.float32)
+            w_scale = scale.T
+            granularity = PER_CHANNEL
+        pw = PackedWeights(packed=pack_codes(jnp.asarray(codes, jnp.int8), bits),
+                           scale=jnp.asarray(scale, dtype=jnp.float32), bits=bits,
+                           k_dim=k, granularity=granularity)
+        bm, bn, bk = {"auto": (None, None, None), "ksteps": (8, 128, 1024),
+                      "chunks": (8, 128, 1024 * fmt.values_per_byte)}[blocks]
+        y = qmm(jnp.asarray(x, dtype=jnp.float32), pw, use_pallas=True,
+                interpret=True, block_m=bm, block_n=bn, block_k=bk)
+        y = np.asarray(y, np.float64)
+        ref = x.astype(np.float64) @ (codes * w_scale.astype(np.float64)
+                                      / fmt.half_steps).T
+        assert np.linalg.norm(y - ref) <= 1e-6 * np.linalg.norm(ref)
 
 
 class TestQmmSemantics:

@@ -20,7 +20,14 @@ from jax.sharding import SingleDeviceSharding
 
 from repro.core import qniht_batch
 from repro.kernels.hsthresh.ops import hsthresh
-from repro.kernels.qmm.kernel import qmm_group_pallas, qmm_pallas, select_block_config
+from repro.kernels.qmm.kernel import (
+    VMEM_BUDGET_BYTES,
+    VMEM_LIMIT_BYTES,
+    qmm_group_pallas,
+    qmm_pallas,
+    select_block_config,
+    vmem_bytes,
+)
 from repro.kernels.sqround.ops import sqround
 from repro.quant.formats import BY_BITS
 
@@ -101,6 +108,24 @@ def test_qmm_group_compiles_at_lofar_width(one_chip, bits, group_size, orient):
         _spec((mp, kp), jnp.float32, one_chip),
         _spec((np_, kp // vpb), jnp.uint8, one_chip),
         _spec((np_, kp // group_size), jnp.float32, one_chip))
+
+
+@pytest.mark.parametrize("orient", sorted(ORIENTATIONS))
+@pytest.mark.parametrize("bits,group_size",
+                         [(2, None), (4, None), (8, None), (2, 64), (4, 64), (8, 64), (4, 16)])
+def test_lofar_tiles_fit_the_requested_vmem(bits, group_size, orient):
+    m, n, k = ORIENTATIONS[orient]
+    blocks = select_block_config(m, n, k, bits, group_size=group_size)
+    assert vmem_bytes(*blocks, bits, group_size) <= VMEM_BUDGET_BYTES <= VMEM_LIMIT_BYTES
+    if group_size is None:   # a grid step streams at least 512 KiB of codes
+        _, bn, bk = blocks
+        assert bn * bk // BY_BITS[bits].values_per_byte >= 512 * 1024
+
+
+def test_adjoint_pads_baselines_to_896():
+    # 870 baselines pad to 7 × 128, not to a multiple of a fixed 512-code step
+    _, _, bk = select_block_config(*ORIENTATIONS["adj"], 8)
+    assert _round_up(M_BASELINES, bk) == 896
 
 
 def test_hsthresh_compiles_at_sky_width(one_chip):
