@@ -51,6 +51,7 @@ is drawn in the MRI literature) and ifft-shifted to the DC-at-[0,0] convention
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Optional
 
 import numpy as np
@@ -229,8 +230,11 @@ def kspace_radial_bands(
     fr = ((row + r // 2) % r) - r // 2
     fc = ((col + r // 2) % r) - r // 2
     dist = jnp.sqrt((fr.astype(jnp.float32)) ** 2 + (fc.astype(jnp.float32)) ** 2)
-    d_max = jnp.sqrt(2.0) * (r / 2.0)
-    band = jnp.floor(dist / d_max * n_bands).astype(jnp.int32)
+    # opaque to XLA's folding, so a compiled map rounds as the op-by-op one:
+    # folded into one factor n_bands/d_max, it moves samples on a band edge
+    two, nb = jax.lax.optimization_barrier((jnp.float32(2.0), jnp.float32(n_bands)))
+    d_max = jnp.sqrt(two) * (r / 2.0)
+    band = jnp.floor(dist / d_max * nb).astype(jnp.int32)
     return jnp.clip(band, 0, n_bands - 1)
 
 
@@ -238,15 +242,39 @@ def kspace_band_scales(y: jax.Array, bands: jax.Array, n_bands: int) -> jax.Arra
     """Per-band quantizer scale: max component magnitude within each radial
     band (real & imaginary share one scale, like the per-tensor quantizer).
     ``y`` is (M,) or batched (..., M); returns (..., n_bands) f32, with empty
-    or all-zero bands guarded to scale 1."""
+    or all-zero bands guarded to scale 1.
+
+    A max over a one-hot (n_bands, M) view, not a scatter: exact, so equal to
+    a segment max bit for bit, and no sort on the TPU."""
     mag = jnp.maximum(jnp.abs(jnp.real(y)), jnp.abs(jnp.imag(y)))
+    in_band = bands == jnp.arange(n_bands, dtype=bands.dtype)[:, None]
+    s = jnp.max(jnp.where(in_band, mag[..., None, :], -jnp.inf), axis=-1)
+    return jnp.where(s > 0, s, jnp.ones_like(s))  # also clears -inf empties
 
-    def one(m):
-        s = jax.ops.segment_max(m, bands, num_segments=n_bands)
-        return jnp.where(s > 0, s, jnp.ones_like(s))  # also clears -inf empties
 
-    flat = mag.reshape(-1, mag.shape[-1])
-    return jax.vmap(one)(flat).reshape(*mag.shape[:-1], n_bands)
+@functools.partial(jax.jit, static_argnames=("resolution", "bits_y", "n_bands"))
+def _quantize_per_band(y, indices, key, resolution, bits_y, n_bands):
+    """The per-band quantizer as one program per (shape, bits, n_bands), the
+    band map included."""
+    with jax.named_scope("yquant"):
+        bands = kspace_radial_bands(indices, resolution, n_bands)
+        scales = kspace_band_scales(y, bands, n_bands)          # (..., n_bands)
+        kre, kim = jax.random.split(key)
+
+        def one(y_row, scale_row):
+            """One acquisition; every batch row folds the same key so that
+            row b of a batched call reproduces the single-row call
+            bit-for-bit (the qniht batching contract)."""
+            s = scale_row[bands]
+            cre, _ = quantize_codes(jnp.real(y_row), bits_y, kre, scale=s)
+            cim, _ = quantize_codes(jnp.imag(y_row), bits_y, kim, scale=s)
+            step = s / BY_BITS[bits_y].half_steps
+            return jax.lax.complex(cre.astype(jnp.float32) * step,
+                                   cim.astype(jnp.float32) * step)
+
+        flat_y = y.reshape(-1, y.shape[-1])
+        flat_s = scales.reshape(-1, n_bands)
+        return jax.vmap(one)(flat_y, flat_s).reshape(y.shape).astype(y.dtype)
 
 
 def quantize_observations(
@@ -270,7 +298,12 @@ def quantize_observations(
     step of its *local* dynamic range, so the huge low-frequency coefficients
     no longer force the quantization step of the tiny high frequencies. Stream
     overhead is ``4 * n_bands`` bytes of f32 scales (band indices are derivable
-    from the sampling mask the acquisition already stores).
+    from the sampling mask the acquisition already stores). It runs as one
+    compiled program per (shape, ``bits_y``, ``n_bands``) under the named
+    scope ``yquant``, the band map included. With ``n_bands=1`` each row
+    takes one scale, its largest component: the paper's single c_y per
+    acquisition, equal bit for bit to the per-tensor quantizer of that row
+    wherever neither its real nor its imaginary part is all zero.
     """
     if granularity == "per_tensor":
         return fake_quantize(y, bits_y, key)
@@ -281,26 +314,9 @@ def quantize_observations(
     if op is None:
         raise ValueError("per_band quantization needs the sensing operator "
                          "(op=...) for the k-space band geometry")
-    bands = kspace_radial_bands(op, n_bands=n_bands)
-    scales = kspace_band_scales(y, bands, n_bands)          # (..., n_bands)
-    kre, kim = jax.random.split(key)
-
-    def one(y_row, scale_row):
-        """One acquisition; every batch row folds the same key so that row b
-        of a batched call reproduces the single-row call bit-for-bit (the
-        qniht batching contract)."""
-        s = scale_row[bands]
-        cre, _ = quantize_codes(jnp.real(y_row), bits_y, kre, scale=s)
-        cim, _ = quantize_codes(jnp.imag(y_row), bits_y, kim, scale=s)
-        step = s / BY_BITS[bits_y].half_steps
-        return jax.lax.complex(cre.astype(jnp.float32) * step,
-                               cim.astype(jnp.float32) * step)
-
-    if y.ndim == 1:
-        return one(y, scales).astype(y.dtype)
-    flat_y = y.reshape(-1, y.shape[-1])
-    flat_s = scales.reshape(-1, n_bands)
-    return jax.vmap(one)(flat_y, flat_s).reshape(y.shape).astype(y.dtype)
+    kop = getattr(op, "kspace_op", op)
+    return _quantize_per_band(y, kop.indices, key, resolution=kop.resolution,
+                              bits_y=bits_y, n_bands=n_bands)
 
 
 def mri_observations(
@@ -330,7 +346,7 @@ def mri_observations(
 SPARSITY_BASES = ("pixel", "haar", "db4")
 
 
-@dataclasses.dataclass
+@dataclasses.dataclass  # jaxlint: allow=JL005 -- host-side bundle of one problem; its arrays enter jit one by one, never the dataclass
 class MRIProblem:
     """One subsampled-Fourier recovery instance (matrix-free Φ throughout).
 

@@ -84,6 +84,22 @@ def test_scopes_nest_backtrack_holds_its_applications():
     assert any("threshold" in c for c in inner)
 
 
+def test_yquant_scope_reaches_lowered_and_compiled_op_names():
+    """The per-band observation quantizer runs under the ``yquant`` scope."""
+    from repro.sensing import quantize_observations
+
+    r = 16
+    mask = np.zeros((r, r), bool)
+    mask.flat[np.random.default_rng(2).choice(r * r, 100, replace=False)] = True
+    op = SubsampledFourierOperator.from_mask(mask)
+    quant = jax.jit(lambda Y: quantize_observations(Y, 8, jax.random.PRNGKey(0),
+                                                    granularity="per_band", op=op,
+                                                    n_bands=4))
+    lowered = quant.lower(jnp.ones((4, op.shape[0]), jnp.complex64))
+    assert "yquant" in _lowered_scopes(lowered.as_text(debug_info=True))
+    assert "yquant" in _compiled_scopes(lowered.compile().as_text())
+
+
 def _easy_batch(B=4, m=40, n=80, s=3):
     key = jax.random.PRNGKey(11)
     base = make_gaussian_problem(m, n, s, None, key)
