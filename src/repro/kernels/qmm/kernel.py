@@ -42,7 +42,10 @@ included; once per M block), x streamed (once per N block) and the first and
 last steps, which the pipeline cannot overlap — under the step's VMEM estimate
 (:func:`vmem_bytes`), which the kernel requests from the compiler
 (:data:`VMEM_LIMIT_BYTES`). Tiles are multiples of the hardware minima, at most
-the aligned problem, so a K of 870 pads to 896, not to a multiple of 512.
+the aligned problem, so a K of 870 takes one 896-code step, not a multiple of
+512. :func:`qmm_pallas` reads the codes and their scale row as stored: where N
+or K is not a block multiple, the grid rounds up and its last block along that
+axis is partial; only x, the small operand, is padded to the K step.
 Explicitly passed block shapes are validated strictly — misalignment, a block
 that pads the problem more than the hardware minima require, or one over the
 VMEM budget raises instead of failing in the TPU compiler.
@@ -72,6 +75,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.quant.formats import BY_BITS
+from repro.quant.pack import packed_len
 
 # Hardware minima the tiles must respect regardless of problem size: 8
 # sublanes (M), 128 lanes (N), and a K step that is whole packed bytes on
@@ -419,21 +423,35 @@ def qmm_pallas(
     block_k: int = 512,
     interpret: bool = False,
 ) -> jax.Array:
-    """Packed low-precision matmul. Shapes must be pre-padded to block multiples:
-    x (M, K), w_packed (N, K/vpb) uint8, scale (1, N). Returns (M, N) f32."""
+    """Packed low-precision matmul over the codes as they are stored.
+
+    x (M, Kp) with M a multiple of ``block_m``, Kp = ``k_dim`` rounded up to
+    ``block_k``, and every column of x past ``k_dim`` zero; w_packed
+    (N, packed_len(k_dim)) uint8 and scale (1, N) f32 for any N. Returns
+    (M, N) f32.
+
+    Nothing of the codes or the scale row is copied: where N or the packed K
+    is not a block multiple, the grid's last block along that axis is partial.
+    Out-of-bounds lanes of a partial output block are never written, so the
+    code rows and scale lanes past N reach nothing. Codes past K are whatever
+    the last block's DMA leaves in VMEM — uint8 words, so finite once
+    unpacked — and meet x's zero columns, so each adds an exact zero."""
     fmt = BY_BITS[bits]
     vpb = fmt.values_per_byte
     m, k = x.shape
     n = w_packed.shape[0]
-    if k != k_dim:
-        raise ValueError(f"x K dim {k} != k_dim {k_dim}")
-    if k % block_k or m % block_m or n % block_n:
-        raise ValueError(f"shapes ({m},{k}),({n}) must be multiples of blocks "
-                         f"({block_m},{block_n},{block_k}); pad in ops.py")
-    if w_packed.shape[1] * vpb != k:
+    if k != _round_up(k_dim, block_k):
+        raise ValueError(f"x K dim {k} != k_dim {k_dim} rounded up to block_k "
+                         f"{block_k}; pad x in ops.py")
+    if m % block_m:
+        raise ValueError(f"x rows {m} must be a multiple of block_m {block_m}; "
+                         "pad x in ops.py")
+    if w_packed.shape[1] != packed_len(k_dim, bits):
         raise ValueError("w_packed minor dim inconsistent with k_dim/bits")
+    if scale.shape != (1, n):
+        raise ValueError(f"scale shape {scale.shape} != (1, N) = (1, {n})")
     bk_packed = block_k // vpb
-    grid = (m // block_m, n // block_n, k // block_k)
+    grid = (m // block_m, pl.cdiv(n, block_n), k // block_k)
     return pl.pallas_call(
         functools.partial(_qmm_kernel, bits=bits),
         grid=grid,

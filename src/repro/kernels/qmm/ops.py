@@ -1,7 +1,7 @@
 """Jit'd public wrappers around the packed low-precision matmul.
 
 * :func:`pack_weights` — quantize + pack a weight/measurement matrix for qmm.
-* :func:`qmm` — padded dispatch: Pallas kernel on TPU, fused blocked
+* :func:`qmm` — dispatch: Pallas kernel on TPU, fused blocked
   pipeline (:func:`qmm_fused`) elsewhere.
 * :func:`qmm_complex` — complex Φ̂ × real/complex vectors via real matmuls.
 * :class:`PackedOperator` / :func:`pack_operator` — both orientations of a CS
@@ -136,7 +136,7 @@ def qmm(
     block_n: Optional[int] = None,
     block_k: Optional[int] = None,
 ) -> jax.Array:
-    """y = x @ dequant(w)ᵀ with padding to kernel block multiples.
+    """y = x @ dequant(w)ᵀ.
 
     ``use_pallas=None`` auto-dispatches: the Mosaic kernel on TPU, the fused
     blocked jnp pipeline (:func:`qmm_fused`) elsewhere (interpret=True forces
@@ -144,7 +144,9 @@ def qmm(
     (``granularity=per_block``) route to the group kernel, whose K blocks are
     additionally aligned to the scale group size. Block shapes default to
     :func:`select_block_config`'s problem-sized choice; explicit values are
-    validated strictly (misalignment or pure-padding tiles raise).
+    validated strictly (misalignment or pure-padding tiles raise). The
+    per-tensor/per-channel kernel takes the codes and scales as stored and
+    only x is padded to the blocks; the group kernel's operands are all padded.
 
     ``w_t`` optionally carries the same quantization packed in the transposed
     orientation (``pack_operator(shared=True)`` stores the pair anyway); the
@@ -159,22 +161,18 @@ def qmm(
     if w.granularity.kind == "per_block":
         return _qmm_group(x, w, interpret, block_m, block_n, block_k)
 
-    vpb = BY_BITS[w.bits].values_per_byte
     bm, bn, bk = select_block_config(m, n, w.k_dim, w.bits,
                                      block_m=block_m, block_n=block_n,
                                      block_k=block_k)
-    mp, np_, kp = _round_up(m, bm), _round_up(n, bn), _round_up(w.k_dim, bk)
-    packed_k = kp // vpb
-    # a named scope on the per-call copies of x, the codes and the scales,
-    # so a profiler trace can tell them from the kernel
+    mp, kp = _round_up(m, bm), _round_up(w.k_dim, bk)
+    # only x is padded (zeros past K, which the codes past K meet): the kernel
+    # reads the codes and scales as stored, with partial edge blocks. A named
+    # scope on the copy, so a profiler trace can tell it from the kernel
     with jax.named_scope("qmm_pad"):
         x_p = jnp.pad(x, ((0, mp - m), (0, kp - k)))
-        w_p = jnp.pad(w.packed, ((0, np_ - n), (0, packed_k - w.packed.shape[1])),
-                      constant_values=_zero_byte(w.bits))
-        s_p = jnp.pad(w.scale, ((0, 0), (0, np_ - n)))
-    y = qmm_pallas(x_p, w_p, s_p, bits=w.bits, k_dim=kp,
+    y = qmm_pallas(x_p, w.packed, w.scale, bits=w.bits, k_dim=w.k_dim,
                    block_m=bm, block_n=bn, block_k=bk, interpret=interpret)
-    return y[:m, :n]
+    return y[:m]
 
 
 def _qmm_group(x, w: PackedWeights, interpret, block_m, block_n, block_k):

@@ -9,12 +9,14 @@ from hypothesis_shim import given, settings, st
 
 from repro.kernels.qmm.kernel import (
     VMEM_BUDGET_BYTES,
+    qmm_pallas,
     select_block_config,
     split_bf16x3,
     vmem_bytes,
 )
 from repro.kernels.qmm.ops import (
     PackedWeights,
+    _zero_byte,
     pack_operator,
     pack_weights,
     packed_matvec,
@@ -24,7 +26,7 @@ from repro.kernels.qmm.ops import (
 from repro.kernels.qmm.ref import qmm_group_ref, qmm_ref
 from repro.quant import fake_quantize
 from repro.quant.formats import BY_BITS, PER_CHANNEL, per_block
-from repro.quant.pack import pack_codes
+from repro.quant.pack import pack_codes, packed_len
 
 BITS = [2, 4, 8]
 
@@ -143,6 +145,85 @@ class TestExactness:
         ref = x.astype(np.float64) @ (codes * w_scale.astype(np.float64)
                                       / fmt.half_steps).T
         assert np.linalg.norm(y - ref) <= 1e-6 * np.linalg.norm(ref)
+
+
+def _padded_route(x, pw, bm, bn, bk, interpret=True):
+    """The kernel over codes and scales padded out to block multiples
+    (biased-zero codes, zero scales): the route that copied them every call."""
+    m, k = x.shape
+    n = pw.packed.shape[0]
+    mp, np_ = -(-m // bm) * bm, -(-n // bn) * bn
+    kp = -(-k // bk) * bk
+    vpb = BY_BITS[pw.bits].values_per_byte
+    x_p = jnp.pad(x, ((0, mp - m), (0, kp - k)))
+    w_p = jnp.pad(pw.packed, ((0, np_ - n), (0, kp // vpb - pw.packed.shape[1])),
+                  constant_values=_zero_byte(pw.bits))
+    s_p = jnp.pad(pw.scale, ((0, 0), (0, np_ - n)))
+    y = qmm_pallas(x_p, w_p, s_p, bits=pw.bits, k_dim=kp, block_m=bm,
+                   block_n=bn, block_k=bk, interpret=interpret)
+    return y[:m, :n]
+
+
+def _jaxpr_eqns(jaxpr):
+    """Every equation of a jaxpr and of the jaxprs nested in its equations."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from _jaxpr_eqns(sub)
+
+
+class TestRaggedEdges:
+    """The per-tensor/per-channel kernel reads the codes and their scale row as
+    stored: its partial last N and K blocks give exactly what the kernel gives
+    over codes padded to block multiples. In interpret mode the blocks' lanes
+    past the arrays read 0 bytes (codes of -half_steps) and NaN scales, so a
+    code past K that met a nonzero x, or a scale past N that reached a written
+    output, would show."""
+
+    # (n, k, block_k): "n" — 300 rows over 128-row blocks; "k" — 870 codes
+    # over several K steps of the least block; "k_wide" — one K block wider
+    # than the 174 codes; "both" — LOFAR's ragged widths at the auto blocks
+    CASES = {"n": (300, 1024, None), "k": (256, 870, "unit"),
+             "k_wide": (256, 174, None), "both": (300, 870, None)}
+
+    @pytest.mark.parametrize("edge", sorted(CASES))
+    @pytest.mark.parametrize("bits", BITS)
+    def test_ragged_blocks_bit_equal_padded_route(self, bits, edge):
+        n, k, block_k = self.CASES[edge]
+        if block_k == "unit":
+            block_k = 128 * BY_BITS[bits].values_per_byte
+        key = jax.random.PRNGKey(bits)
+        x = jax.random.normal(key, (8, k), jnp.float32)
+        w = jax.random.normal(jax.random.fold_in(key, 1), (n, k), jnp.float32)
+        pw = pack_weights(w, bits, jax.random.fold_in(key, 2))
+        bm, bn, bk = select_block_config(8, n, k, bits, block_n=128, block_k=block_k)
+        assert n % bn or k % bk              # the case has a ragged edge
+        out = qmm(x, pw, use_pallas=True, interpret=True, block_n=bn, block_k=block_k)
+        np.testing.assert_array_equal(np.asarray(out),
+                                      np.asarray(_padded_route(x, pw, bm, bn, bk)))
+        ref = qmm_ref(x, pw.packed, pw.scale, bits, k)
+        np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
+                                   rtol=1e-5, atol=1e-4)
+
+    @pytest.mark.parametrize("orient", ["fwd", "adj"])
+    @pytest.mark.parametrize("bits", BITS)
+    def test_lofar_code_planes_are_never_copied(self, bits, orient):
+        # LOFAR CS302: 870 baselines × a 256² sky, an 8-row batch
+        m, n, k = {"fwd": (8, 870, 65536), "adj": (8, 65536, 870)}[orient]
+
+        def call(x, packed, scale):
+            return qmm(x, PackedWeights(packed, scale, bits, k), use_pallas=True)
+
+        jaxpr = jax.make_jaxpr(call)(
+            jax.ShapeDtypeStruct((m, k), jnp.float32),
+            jax.ShapeDtypeStruct((n, packed_len(k, bits)), jnp.uint8),
+            jax.ShapeDtypeStruct((1, n), jnp.float32))
+        eqns = list(_jaxpr_eqns(jaxpr.jaxpr))
+        assert any(e.primitive.name == "pallas_call" for e in eqns)
+        padded = [e.invars[0].aval.dtype for e in eqns if e.primitive.name == "pad"]
+        assert jnp.uint8 not in padded
+        if orient == "adj":                  # x alone is padded, 870 → K step
+            assert padded == [jnp.float32]
 
 
 class TestQmmSemantics:

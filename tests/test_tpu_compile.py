@@ -95,6 +95,23 @@ def test_qmm_compiles_at_lofar_width(one_chip, bits, orient):
 
 
 @pytest.mark.parametrize("orient", sorted(ORIENTATIONS))
+@pytest.mark.parametrize("bits", [2, 4, 8])
+def test_qmm_compiles_unpadded_at_lofar_width(one_chip, bits, orient):
+    # the codes and scale row as stored, 870 wide: the grid's last block is
+    # partial along N (forward) or along the packed K (adjoint)
+    m, n, k = ORIENTATIONS[orient]
+    bm, bn, bk = select_block_config(m, n, k, bits)
+    mp, kp = _round_up(m, bm), _round_up(k, bk)
+    vpb = BY_BITS[bits].values_per_byte
+    _compile_kernel(
+        lambda x, w, s: qmm_pallas(x, w, s, bits=bits, k_dim=k, block_m=bm,
+                                   block_n=bn, block_k=bk),
+        _spec((mp, kp), jnp.float32, one_chip),
+        _spec((n, -(-k // vpb)), jnp.uint8, one_chip),
+        _spec((1, n), jnp.float32, one_chip))
+
+
+@pytest.mark.parametrize("orient", sorted(ORIENTATIONS))
 @pytest.mark.parametrize("bits,group_size", [(2, 64), (4, 64), (8, 64), (4, 16)])
 def test_qmm_group_compiles_at_lofar_width(one_chip, bits, group_size, orient):
     m, n, k = ORIENTATIONS[orient]
