@@ -46,7 +46,9 @@ mode — see ``docs/architecture.md``.
 the streaming histogram-select-mask kernel (paper §8's FPGA top-S search);
 support size stays ≤ s by construction.
 
-Everything is a ``lax.scan`` over iterations → one compiled program, traces out.
+One ``lax.while_loop`` over iterations (:func:`_segment_core`) serves every
+entry point — one-shot, sharded, segmented and checkpointed, the continuous
+scheduler — each compiled into one program that returns the trace.
 """
 from __future__ import annotations
 
@@ -87,7 +89,7 @@ class IHTResult(NamedTuple):
 
 
 # consecutive sub-tol updates required before the early-exit freeze rule
-# (exit_tol > 0) declares a row stalled — see _qniht_core
+# (exit_tol > 0) declares a row stalled — see _segment_core
 _EXIT_PATIENCE = 3
 
 
@@ -223,7 +225,7 @@ def niht_iteration(
 
 def _validate(phi, bits_phi, bits_y, key, requantize, backend, threshold, real_signal,
               scale_granularity="per_tensor", group_size=None, early_exit=False,
-              exit_tol=0.0, unroll=1):
+              exit_tol=0.0):
     if (bits_phi or bits_y) and key is None:
         raise ValueError("quantized NIHT needs a PRNG key")
     if requantize not in ("pair", "fixed"):
@@ -238,13 +240,6 @@ def _validate(phi, bits_phi, bits_y, key, requantize, backend, threshold, real_s
         raise ValueError(f"exit_tol must be >= 0, got {exit_tol}")
     if exit_tol > 0.0 and not early_exit:
         raise ValueError("exit_tol is the early_exit freeze tolerance; set early_exit=True")
-    if unroll < 1:
-        raise ValueError(f"unroll must be a positive int, got {unroll}")
-    if unroll > 1 and early_exit:
-        raise ValueError(
-            "unroll amortizes dispatch of the fixed-trip lax.scan; the "
-            "early_exit path is a lax.while_loop with a data-dependent trip "
-            "count, which cannot unroll — use unroll with early_exit=False")
     if backend not in ("dense", "packed"):
         raise ValueError(f"unknown backend {backend!r} (use 'dense' or 'packed')")
     gran = as_granularity(scale_granularity, group_size)  # validates the spelling
@@ -279,13 +274,12 @@ def _solver_setup(
     c, shrink_k, max_backtracks, real_signal, nonneg, with_trace,
     scale_granularity, group_size,
 ):
-    """Shared prologue of the one-shot core and the segmented runner.
-
-    Returns ``(X0, iteration)`` where ``iteration(X, i)`` is one Algorithm 1
-    step at global iteration index ``i``. Everything stochastic — the ŷ draw
-    and the per-iteration Φ̂ pair factory — is derived deterministically from
-    ``key``, and ``iteration`` consumes the *global* index, so running the
-    range [0, n) in one scan or in segments produces bit-identical iterates.
+    """Prologue of the solver loop: returns ``iteration(X, i)``, one
+    Algorithm 1 step at global iteration index ``i``. Everything stochastic —
+    the ŷ draw and the per-iteration Φ̂ pair factory — is derived
+    deterministically from ``key``, and ``iteration`` consumes the *global*
+    index, so running the range [0, n) in one loop or in segments produces
+    bit-identical iterates.
     """
     key = key if key is not None else jax.random.PRNGKey(0)
     ky, kphi = jax.random.split(key)
@@ -294,12 +288,6 @@ def _solver_setup(
     # batch row b reproduces the single-problem run with the same key.
     Yhat = jax.vmap(lambda yy: fake_quantize(yy, bits_y, ky))(Y) if bits_y else Y
 
-    n = phi.shape[1]
-    x_dtype = jnp.float32 if real_signal else (
-        phi.dtype if jnp.issubdtype(jnp.dtype(phi.dtype), jnp.complexfloating)
-        else jnp.float32
-    )
-    X0 = jnp.zeros((Y.shape[0], n), dtype=x_dtype)
     hs = _make_hs(threshold, s)
     phi_true, get_ops = make_iteration_operators(
         phi, bits_phi, requantize, backend, kphi,
@@ -324,132 +312,7 @@ def _solver_setup(
             rq = rt = jnp.asarray(np.full(X.shape[0], np.nan, np.float32))
         return X_new, (rq, rt, mu, changed, n_bt)
 
-    return X0, iteration
-
-
-def _qniht_core(
-    phi, Y, s, n_iters, bits_phi, bits_y, key, requantize, backend, threshold,
-    c, shrink_k, max_backtracks, real_signal, nonneg, with_trace,
-    scale_granularity="per_tensor", group_size=None, early_exit=False,
-    exit_tol=0.0, unroll=1,
-):
-    """Shared batched implementation behind qniht / qniht_batch (Y is (B, M)).
-
-    ``early_exit=True`` tracks a per-row convergence flag and, once EVERY row
-    of this batch is converged, stops executing iteration bodies: the loop
-    over iterations becomes a ``lax.while_loop`` that terminates early and
-    the remaining trace rows are broadcast-filled with the stationary row
-    (NOT a scan of ``lax.cond`` — under SPMD partitioning XLA rewrites a
-    cond into a select that executes both branches, which would silently
-    undo the skip; see the comment in the implementation). Two flavours,
-    selected by ``exit_tol``:
-
-    * ``exit_tol == 0.0`` (lossless): a row is converged when ``x`` reached a
-      bitwise fixed point of the iteration map. Because the map is a
-      deterministic function of ``x`` when the per-iteration operators are
-      stationary (``requantize="fixed"``, packed, matrix-free, or full
-      precision), a bitwise fixed point is absorbing and the recomputed
-      ``(mu, changed, backtracks, resid)`` would be identical — so the output
-      is bit-for-bit the same as ``early_exit=False``, only cheaper.
-    * ``exit_tol > 0.0`` (freeze): a row is *frozen* — its state masked to
-      stop updating — once its relative update stalls:
-      ``‖x⁺−x‖ ≤ exit_tol·‖x⁺‖`` for ``_EXIT_PATIENCE`` consecutive
-      iterations (a single tiny step can be a backtracking artefact, not a
-      stall). This catches rows orbiting tiny limit cycles (low-order bits
-      oscillating around the noise floor) that never hit an exact fixed
-      point. It is a *heuristic* serving trade-off: a row drifting slowly
-      toward a support change (a long saddle plateau) can be frozen short of
-      the escape the full run would eventually make, so frozen results match
-      the full run only up to the quality the stall point already reached —
-      the scaling benchmark records recovery error for both paths to keep
-      that trade visible. No longer bit-identical to
-      ``early_exit=False``, but the rule is deterministic and **row-local**
-      (it reads only the row's own trajectory), so results are bit-identical
-      across ANY row grouping — single device, any mesh width — at the same
-      tolerance.
-
-    This per-row flag is the solver state the sharded serving path splits
-    over the device mesh: a shard whose rows all converged stops paying for
-    iterations while other shards keep working (:mod:`repro.parallel.batch`).
-
-    ``unroll`` is handed to ``lax.scan`` (identical numerics, fewer dispatch
-    boundaries — matters for small per-shard programs on CPU). It applies
-    only to the fixed-trip scan: the early-exit while_loop's trip count is
-    data-dependent and cannot unroll (validated as mutually exclusive).
-    """
-    B = Y.shape[0]
-    X0, iteration = _solver_setup(
-        phi, Y, s, bits_phi, bits_y, key, requantize, backend, threshold,
-        c, shrink_k, max_backtracks, real_signal, nonneg, with_trace,
-        scale_granularity, group_size)
-
-    if not early_exit:
-        X_final, (rq, rt, mus, ch, bt) = jax.lax.scan(
-            lambda X, i: iteration(X, i), X0, jnp.arange(n_iters), unroll=unroll)
-        k_end = jnp.asarray(n_iters, jnp.int32)
-    else:
-        # A while_loop, NOT a scan-of-cond: under SPMD partitioning
-        # (shard_map) XLA rewrites a cond into a select that executes BOTH
-        # branches, which would silently undo the skip; a loop's trip count
-        # cannot be select-ified, so converged shards genuinely stop paying.
-        # Trace rows are written into preallocated buffers as iterations
-        # execute; the stationary tail is broadcast-filled after the loop.
-        def body(st):
-            if exit_tol == 0.0:
-                # a done row recomputes itself identically (fixed point) —
-                # no masking needed, and the no-early-exit output is
-                # reproduced bit-for-bit. The lossless carry has no streak
-                # component: streak feeds only the stall heuristic below.
-                k, X, done, prev, bufs = st
-                X_new, outs = iteration(X, k)
-                bufs = jax.tree_util.tree_map(
-                    lambda buf, o: jax.lax.dynamic_update_index_in_dim(buf, o, k, 0),
-                    bufs, outs)
-                newly = jnp.all(X_new == X, axis=-1)
-                return k + 1, X_new, done | newly, outs, bufs
-            k, X, done, streak, prev, bufs = st
-            X_c, outs_c = iteration(X, k)
-            # frozen rows stop updating; their trace re-emits the last
-            # live row (deterministic + row-local → grouping-invariant)
-            X_new = jnp.where(done[:, None], X, X_c)
-            outs = jax.tree_util.tree_map(
-                lambda p, n_: jnp.where(done, p, n_), prev, outs_c)
-            bufs = jax.tree_util.tree_map(
-                lambda buf, o: jax.lax.dynamic_update_index_in_dim(buf, o, k, 0),
-                bufs, outs)
-            # one sub-tol step can be a backtracking artefact (µ shrunk to
-            # a tiny accepted step), not a stall — require _EXIT_PATIENCE
-            # consecutive sub-tol updates before freezing the row
-            small = _rows_sqnorm(X_new - X) <= (
-                exit_tol * exit_tol) * _rows_sqnorm(X_new)
-            streak = jnp.where(small, streak + 1, 0)
-            newly = streak >= _EXIT_PATIENCE
-            return k + 1, X_new, done | newly, streak, outs, bufs
-
-        def cond(st):
-            return (st[0] < n_iters) & ~jnp.all(st[2])
-
-        nanrow = jnp.asarray(np.full(B, np.nan, np.float32))  # np-built: see sanitize note above
-        prev0 = (nanrow, nanrow, jnp.zeros((B,), jnp.float32),
-                 jnp.zeros((B,), bool), jnp.zeros((B,), jnp.int32))
-        bufs0 = jax.tree_util.tree_map(
-            lambda o: jnp.zeros((n_iters,) + o.shape, o.dtype), prev0)
-        init = (jnp.asarray(0, jnp.int32), X0, jnp.zeros((B,), bool),
-                jnp.zeros((B,), jnp.int32), prev0, bufs0)
-        if exit_tol == 0.0:
-            init = init[:3] + init[4:]
-        out = jax.lax.while_loop(cond, body, init)
-        k_end, X_final, last, bufs = out[0], out[1], out[-2], out[-1]
-        # iterations k_end.. would all re-emit the stationary trace row (every
-        # row is at a fixed point / frozen), so fill instead of compute
-        tail = jnp.arange(n_iters)[:, None] >= k_end
-        (rq, rt, mus, ch, bt) = jax.tree_util.tree_map(
-            lambda buf, o: jnp.where(tail, o[None, :], buf), bufs, last)
-    return IHTResult(
-        x=X_final,
-        trace=IHTTrace(resid_q=rq, resid_true=rt, mu=mus, support_changed=ch, backtracks=bt),
-        iters_run=jnp.full((B,), k_end, jnp.int32),
-    )
+    return iteration
 
 
 class SolverState(NamedTuple):
@@ -505,10 +368,9 @@ def solver_init(
     early_exit: bool = False, exit_tol: float = 0.0,
 ) -> SolverState:
     """Fresh :class:`SolverState` for ``qniht_batch(phi, Y, s, n_iters, ...)``
-    run in segments. Same validation and defaults as :func:`qniht_batch`
-    (``unroll`` excepted: segments run a ``lax.while_loop``, which cannot
-    unroll). Composable under :func:`jax.eval_shape` — that is how the
-    checkpoint restore target is built without touching data."""
+    run in segments. Same validation and defaults as :func:`qniht_batch`,
+    whose own state this is. Composable under :func:`jax.eval_shape` — that
+    is how the checkpoint restore target is built without touching data."""
     if Y.ndim != 2:
         raise ValueError("solver_init expects Y of shape (B, M); wrap one y as y[None]")
     _validate(phi, bits_phi, bits_y, key, requantize, backend, threshold,
@@ -538,13 +400,14 @@ def solver_init(
     )
 
 
-# solver_segment statics: n_iters lives in the trace buffer shape and unroll
-# is scan-only, otherwise identical to _STATIC (shared spelling, not copied)
-_SEG_STATIC = (
-    "n_steps", "s", "bits_phi", "bits_y", "requantize", "backend", "threshold",
+# the solver configuration, static in every entry point's jit
+_STATIC = (
+    "s", "n_iters", "bits_phi", "bits_y", "requantize", "backend", "threshold",
     "c", "shrink_k", "max_backtracks", "real_signal", "nonneg", "with_trace",
     "scale_granularity", "group_size", "early_exit", "exit_tol",
 )
+# the loop's: n_iters lives in the trace buffer shape, a segment advances n_steps
+_SEG_STATIC = tuple("n_steps" if a == "n_iters" else a for a in _STATIC)
 
 # one source of truth for the solver-config defaults of the segmented entry
 # points (solver_segment keyword defaults and the sharded/resilient drivers)
@@ -561,23 +424,45 @@ def _segment_core(
     phi, state: SolverState, *, n_steps, s, bits_phi, bits_y, requantize,
     backend, threshold, c, shrink_k, max_backtracks, real_signal, nonneg,
     with_trace, scale_granularity, group_size, early_exit, exit_tol,
-) -> SolverState:
-    """Advance ``state`` by up to ``n_steps`` iterations (fewer only at the
-    horizon). The loop body is the same ``iteration`` closure the one-shot
-    core runs — segment boundaries are exact restart points because every
-    stochastic input is re-derived from (``Y``, ``key``) and the body consumes
-    the global index ``k``.
+) -> tuple[SolverState, jax.Array]:
+    """The solver's one loop over iterations: advance ``state`` by up to
+    ``n_steps`` iterations (fewer only at the horizon ``n_iters``). Returns
+    the new state and, per row, the index ``k_stop`` the loop stopped at —
+    from a fresh state, the loop's own trip count (the one-shot entry points'
+    ``iters_run``). Every stochastic input is re-derived from (``Y``, ``key``)
+    and the body consumes the global index ``k``, so segment boundaries are
+    exact restart points: any split of ``[0, n_iters)`` — one segment for the
+    one-shot entry points — gives bit-identical results.
 
-    Early exit inside a segment: once every row is done, the remaining rows of
-    the segment's trace range are *filled* with the stationary row instead of
-    computed — bit-identical by the fixed-point/freeze argument in
-    :func:`_qniht_core` — so ``k`` always lands on ``min(k + n_steps,
-    n_iters)``, uniformly across shards. That keeps ``k`` replicated (the
-    sharded path's out-spec) and the state independent of the mesh width it
-    was computed on, which is what makes elastic resume possible.
+    ``early_exit`` stops executing iteration bodies once EVERY row (of this
+    shard) is done. A ``lax.while_loop``, NOT a scan of ``lax.cond``: under
+    SPMD partitioning (``shard_map``) XLA rewrites a cond into a select that
+    executes both branches, which would silently undo the skip; a loop's trip
+    count cannot be select-ified, so a converged shard genuinely stops paying
+    while other shards keep working (:mod:`repro.parallel.batch`). The
+    skipped rows of the trace would all re-emit the stationary row, so they
+    are filled instead of computed and ``k`` lands on ``min(k + n_steps,
+    n_iters)`` on every shard: ``k`` stays replicated and the state
+    independent of the mesh width it ran on (elastic resume). Two flavours:
+
+    * ``exit_tol == 0.0`` (lossless): a row is done at a bitwise fixed point
+      of the iteration map. With stationary operators (``requantize="fixed"``,
+      packed, matrix-free, full precision) the map is a deterministic function
+      of ``x``, so a fixed point is absorbing — a done row recomputes itself
+      identically and the output is bit-for-bit that of ``early_exit=False``.
+    * ``exit_tol > 0.0`` (freeze): a row is frozen — masked, its trace
+      re-emitting its last live row — once ``‖x⁺−x‖ ≤ exit_tol·‖x⁺‖`` for
+      ``_EXIT_PATIENCE`` consecutive iterations (one tiny step can be a
+      backtracking artefact, not a stall). It catches rows orbiting tiny limit
+      cycles that never hit an exact fixed point. A *heuristic* serving
+      trade-off: a row on a long saddle plateau can freeze short of a late
+      support escape, so it matches the full run only up to the quality its
+      stall point reached (the scaling benchmark records both errors). The
+      rule is deterministic and **row-local**, so results are bit-identical
+      across ANY row grouping — single device, any mesh width.
     """
     n_iters = state.trace.mu.shape[0]
-    _, iteration = _solver_setup(
+    iteration = _solver_setup(
         phi, state.Y, s, bits_phi, bits_y, state.key, requantize, backend,
         threshold, c, shrink_k, max_backtracks, real_signal, nonneg, with_trace,
         scale_granularity, group_size)
@@ -588,7 +473,7 @@ def _segment_core(
         X_c, outs_c = iteration(X, k)
         if exit_tol == 0.0:
             # a done row recomputes itself identically (fixed point) — no
-            # masking needed; see _qniht_core
+            # masking needed
             X_new, outs = X_c, outs_c
         else:
             X_new = jnp.where(done[:, None], X, X_c)
@@ -622,8 +507,9 @@ def _segment_core(
         fill = (rows >= k_stop) & (rows < k_end)
         bufs = jax.tree_util.tree_map(
             lambda buf, o: jnp.where(fill, o[None, :], buf), bufs, last)
-    return SolverState(k=k_end, X=X, done=done, streak=streak, last=last,
-                       trace=bufs, Y=state.Y, key=state.key)
+    return (SolverState(k=k_end, X=X, done=done, streak=streak, last=last,
+                        trace=bufs, Y=state.Y, key=state.key),
+            jnp.full(done.shape, k_stop, jnp.int32))
 
 
 _segment_jit = partial(jax.jit, static_argnames=_SEG_STATIC)(_segment_core)
@@ -653,13 +539,14 @@ def solver_segment(
         raise ValueError(f"n_steps must be >= 1, got {n_steps}")
     _validate(phi, bits_phi, bits_y, state.key, requantize, backend, threshold,
               real_signal, scale_granularity, group_size, early_exit, exit_tol)
-    return _segment_jit(
+    state, _ = _segment_jit(
         phi, state, n_steps=n_steps, s=s, bits_phi=bits_phi, bits_y=bits_y,
         requantize=requantize, backend=backend, threshold=threshold, c=c,
         shrink_k=shrink_k, max_backtracks=max_backtracks,
         real_signal=real_signal, nonneg=nonneg, with_trace=with_trace,
         scale_granularity=scale_granularity, group_size=group_size,
         early_exit=early_exit, exit_tol=exit_tol)
+    return state
 
 
 def solver_result(state: SolverState) -> IHTResult:
@@ -678,11 +565,12 @@ def solver_result(state: SolverState) -> IHTResult:
                      iters_run=jnp.full(state.X.shape[:1], state.k, jnp.int32))
 
 
-_STATIC = (
-    "s", "n_iters", "bits_phi", "bits_y", "requantize", "backend", "threshold",
-    "c", "shrink_k", "max_backtracks", "real_signal", "nonneg", "with_trace",
-    "scale_granularity", "group_size", "early_exit", "exit_tol", "unroll",
-)
+def _qniht_core(phi, Y, key, *, n_iters, **cfg) -> IHTResult:
+    """The one-shot solve of (B, M) ``Y``: a fresh state advanced through
+    all ``n_iters`` in one segment, ``iters_run`` the loop's trip count."""
+    state = solver_init(phi, Y, n_iters=n_iters, key=key, **cfg)
+    state, iters = _segment_core(phi, state, n_steps=n_iters, **cfg)
+    return solver_result(state)._replace(iters_run=iters)
 
 
 @partial(jax.jit, static_argnames=_STATIC)
@@ -708,7 +596,6 @@ def qniht(
     group_size: Optional[int] = None,
     early_exit: bool = False,
     exit_tol: float = 0.0,
-    unroll: int = 1,
 ) -> IHTResult:
     """Low-precision NIHT (Algorithm 1). ``bits_phi=bits_y=None`` → plain NIHT.
 
@@ -743,19 +630,19 @@ def qniht(
         only); see :mod:`repro.quant.formats` for layout and overhead.
       early_exit: skip remaining iteration bodies once x reaches a bitwise
         fixed point (stationary operators only — bit-identical output, see
-        :func:`_qniht_core`).
+        :func:`_segment_core`).
     """
     if y.ndim != 1:
         raise ValueError(
             f"qniht expects y of shape (M,), got ndim={y.ndim}; "
             "use qniht_batch for a (B, M) stack of observations")
-    _validate(phi, bits_phi, bits_y, key, requantize, backend, threshold, real_signal,
-              scale_granularity, group_size, early_exit, exit_tol, unroll)
     res = _qniht_core(
-        phi, y[None, :], s, n_iters, bits_phi, bits_y, key, requantize, backend,
-        threshold, c, shrink_k, max_backtracks, real_signal, nonneg, with_trace,
-        scale_granularity, group_size, early_exit, exit_tol, unroll,
-    )
+        phi, y[None, :], key, n_iters=n_iters, s=s, bits_phi=bits_phi,
+        bits_y=bits_y, requantize=requantize, backend=backend,
+        threshold=threshold, c=c, shrink_k=shrink_k,
+        max_backtracks=max_backtracks, real_signal=real_signal, nonneg=nonneg,
+        with_trace=with_trace, scale_granularity=scale_granularity,
+        group_size=group_size, early_exit=early_exit, exit_tol=exit_tol)
     return IHTResult(
         x=res.x[0],
         trace=jax.tree_util.tree_map(lambda t: t[:, 0], res.trace),
@@ -786,7 +673,6 @@ def qniht_batch(
     group_size: Optional[int] = None,
     early_exit: bool = False,
     exit_tol: float = 0.0,
-    unroll: int = 1,
 ) -> IHTResult:
     """Recover B observation vectors of the same Φ at once (heavy-traffic mode).
 
@@ -804,19 +690,19 @@ def qniht_batch(
 
     ``early_exit=True`` skips remaining iteration bodies once EVERY row has
     reached a bitwise fixed point — bit-identical output, cheaper tail
-    (stationary operators only; see :func:`_qniht_core`). Most valuable
+    (stationary operators only; see :func:`_segment_core`). Most valuable
     through :func:`qniht_batch_sharded`, where the all-rows condition is per
     shard rather than per batch.
     """
     if Y.ndim != 2:
         raise ValueError("qniht_batch expects Y of shape (B, M); use qniht for one y")
-    _validate(phi, bits_phi, bits_y, key, requantize, backend, threshold, real_signal,
-              scale_granularity, group_size, early_exit, exit_tol, unroll)
     return _qniht_core(
-        phi, Y, s, n_iters, bits_phi, bits_y, key, requantize, backend,
-        threshold, c, shrink_k, max_backtracks, real_signal, nonneg, with_trace,
-        scale_granularity, group_size, early_exit, exit_tol, unroll,
-    )
+        phi, Y, key, n_iters=n_iters, s=s, bits_phi=bits_phi,
+        bits_y=bits_y, requantize=requantize, backend=backend,
+        threshold=threshold, c=c, shrink_k=shrink_k,
+        max_backtracks=max_backtracks, real_signal=real_signal, nonneg=nonneg,
+        with_trace=with_trace, scale_granularity=scale_granularity,
+        group_size=group_size, early_exit=early_exit, exit_tol=exit_tol)
 
 
 def qniht_batch_sharded(
@@ -843,7 +729,6 @@ def qniht_batch_sharded(
     group_size: Optional[int] = None,
     early_exit: bool = True,
     exit_tol: float = 0.0,
-    unroll: int = 1,
 ) -> IHTResult:
     """:func:`qniht_batch` with the B axis split over a 1-D ``batch`` device
     mesh — the multi-device serving mode.
@@ -855,9 +740,8 @@ def qniht_batch_sharded(
     operator's parameters) are replicated, and every piece of per-item solver
     state — ``x``, support, step size µ, backtrack counters, convergence
     flags — lives with its rows. B need not divide the mesh: rows are
-    zero-padded to the next multiple (an all-zero row converges at iteration
-    0, so padding never delays a shard) and the padding is stripped from the
-    result.
+    padded to the next multiple with zero rows born converged (so padding
+    never delays a shard) and the padding is stripped from the result.
 
     Contract: item ``b`` computes exactly what ``qniht_batch(phi, Y, ...)``
     computes on one device — same quantization draws (the key is replicated
@@ -870,7 +754,7 @@ def qniht_batch_sharded(
     computed, plus the ``early_exit`` default (True here: per-shard
     convergence is the point — a shard of converged rows stops iterating
     instead of riding along with the slowest item in the global batch; see
-    :func:`_qniht_core`).
+    :func:`_segment_core`).
 
     All other arguments exactly as :func:`qniht_batch`, and every backend
     works sharded: dense, fake-quant, packed (all scale granularities), and
@@ -879,8 +763,6 @@ def qniht_batch_sharded(
     """
     if Y.ndim != 2:
         raise ValueError("qniht_batch_sharded expects Y of shape (B, M)")
-    _validate(phi, bits_phi, bits_y, key, requantize, backend, threshold, real_signal,
-              scale_granularity, group_size, early_exit, exit_tol, unroll)
     from repro.parallel.batch import sharded_qniht_run
 
     return sharded_qniht_run(
@@ -889,7 +771,7 @@ def qniht_batch_sharded(
         threshold=threshold, c=c, shrink_k=shrink_k, max_backtracks=max_backtracks,
         real_signal=real_signal, nonneg=nonneg, with_trace=with_trace,
         scale_granularity=scale_granularity, group_size=group_size,
-        early_exit=early_exit, exit_tol=exit_tol, unroll=unroll,
+        early_exit=early_exit, exit_tol=exit_tol,
     )
 
 

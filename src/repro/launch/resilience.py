@@ -79,7 +79,7 @@ def recover_resilient(
     """``qniht_batch(phi, Y, s, n_iters, ...)`` with segment checkpoints.
 
     Accepts the batched solver's keyword configuration (``bits_phi``,
-    ``backend`` ... — everything except ``unroll``, which is scan-only).
+    ``backend`` ...).
     ``mesh``/``n_devices`` selects the sharded segment engine
     (:func:`repro.parallel.batch.sharded_segment_run`); the checkpoint itself
     is mesh-agnostic either way.

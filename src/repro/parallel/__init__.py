@@ -11,7 +11,6 @@
 from repro.parallel.batch import (
     BatchServer,
     make_batch_mesh,
-    pad_batch,
     pad_state,
     refill_rows,
     sharded_qniht_run,
@@ -48,7 +47,6 @@ __all__ = [
     "Request",
     "RequestReport",
     "make_batch_mesh",
-    "pad_batch",
     "pad_state",
     "refill_rows",
     "segment_step",

@@ -22,14 +22,18 @@ parallel, and this module maps it onto a mesh:
   are; the LOFAR CS302 int2 batch (16 rows on one TPU v5e chip vs 4 per chip
   on four) differs by up to 1.2e-7 per item.
 
-Implementation: :func:`jax.shard_map` around the shared batched core
-``repro.core.niht._qniht_core`` (``check_vma=False`` — the program contains
-no collectives, shards are fully independent, so there is nothing for the
-varying-manual-axes check to verify). B is
-zero-padded up to a multiple of the mesh size; an all-zero row is accepted at
-iteration 0 and immediately flagged converged, so padding never slows a shard
-down. ``jax.jit`` over static solver config gives the compile cache the
-serving loop relies on: a stream of equally-shaped chunks compiles once.
+Implementation: one :func:`jax.shard_map` around the solver's one loop,
+``repro.core.niht._segment_core``, over a row-sharded
+:class:`~repro.core.niht.SolverState` (``check_vma=False`` — the program
+contains no collectives, shards are fully independent, so there is nothing
+for the varying-manual-axes check to verify). A one-shot chunk
+(:func:`sharded_qniht_run`) builds its state, pads it, runs the loop for all
+``n_iters``, strips the padding and wraps the result inside one ``jax.jit``;
+a segment (:func:`sharded_segment_run`) runs the same loop on a state it is
+handed. The rows are padded up to a multiple of the mesh size with rows
+born converged (:func:`pad_state`), so padding never slows a shard down.
+``jax.jit`` over static solver config gives the compile cache the serving
+loop relies on: a stream of equally-shaped chunks compiles once.
 
 :class:`BatchServer` is the multi-chunk driver: fixed chunk shape, operator
 packed ONCE at construction (the packed backend's quantize+pack leaves the
@@ -58,9 +62,10 @@ from repro.core.niht import (
     IHTResult,
     IHTTrace,
     SolverState,
-    _qniht_core,
     _segment_core,
     _validate,
+    solver_init,
+    solver_result,
 )
 from repro.core.operators import PackedStreamingOperator
 from repro.parallel.journal import ChunkJournal
@@ -83,19 +88,6 @@ def force_host_devices(n: int, env=None) -> None:
     target["XLA_FLAGS"] = (target.get("XLA_FLAGS", "")
                            + f" --xla_force_host_platform_device_count={int(n)}")
 
-# the solver's own static-argname list — shared, not copied, so a kwarg added
-# to the single-device jit can never silently become a traced argument here
-_CORE_STATICS = _STATIC
-
-# x is (B_local, N) → rows sharded; trace arrays are (n_iters, B_local) → the
-# batch axis is second; iters_run is (B_local,), each shard's own trip count
-# repeated on its rows. The operator/key inputs are replicated (P() prefix).
-_OUT_SPECS = IHTResult(
-    x=P(BATCH_AXIS),
-    trace=IHTTrace(*([P(None, BATCH_AXIS)] * 5)),
-    iters_run=P(BATCH_AXIS),
-)
-
 
 def make_batch_mesh(n_devices: Optional[int] = None, devices=None) -> Mesh:
     """1-D serving mesh over the local devices, axis name ``"batch"``.
@@ -114,65 +106,6 @@ def make_batch_mesh(n_devices: Optional[int] = None, devices=None) -> Mesh:
                 "initializes")
         devs = devs[:n_devices]
     return Mesh(np.array(devs).reshape(len(devs)), (BATCH_AXIS,))
-
-
-def pad_batch(Y: jax.Array, n_shards: int) -> tuple[jax.Array, int]:
-    """Zero-pad rows of (B, M) ``Y`` up to a multiple of ``n_shards``.
-
-    Returns ``(Y_padded, B)``. Zero rows are free riders: NIHT accepts x = 0
-    for y = 0 at the first iteration, so the convergence flag of a padding row
-    is set immediately and ``early_exit`` shards never wait on it.
-    """
-    b = Y.shape[0]
-    b_pad = -(-b // n_shards) * n_shards
-    if b_pad == b:
-        return Y, b
-    pad = jnp.zeros((b_pad - b, Y.shape[1]), Y.dtype)
-    return jnp.concatenate([Y, pad], axis=0), b
-
-
-@partial(jax.jit, static_argnames=("mesh",) + _CORE_STATICS)
-def _sharded_call(phi, Y, key, *, mesh, **statics):
-    def local(phi_, Y_, key_):
-        return _qniht_core(
-            phi_, Y_, statics["s"], statics["n_iters"], statics["bits_phi"],
-            statics["bits_y"], key_, statics["requantize"], statics["backend"],
-            statics["threshold"], statics["c"], statics["shrink_k"],
-            statics["max_backtracks"], statics["real_signal"], statics["nonneg"],
-            statics["with_trace"], statics["scale_granularity"],
-            statics["group_size"], statics["early_exit"], statics["exit_tol"],
-            statics["unroll"],
-        )
-
-    fn = jax.shard_map(
-        local, mesh=mesh,
-        in_specs=(P(), P(BATCH_AXIS), P()),
-        out_specs=_OUT_SPECS,
-        check_vma=False,
-    )
-    return fn(phi, Y, key)
-
-
-def sharded_qniht_run(phi, Y, key, *, mesh=None, n_devices=None, **statics) -> IHTResult:
-    """Pad → shard_map the batched NIHT core → strip padding.
-
-    The backend of :func:`repro.core.niht.qniht_batch_sharded`; call that
-    instead (it validates the solver configuration first).
-    """
-    mesh = mesh if mesh is not None else make_batch_mesh(n_devices)
-    if set(mesh.axis_names) != {BATCH_AXIS}:
-        raise ValueError(
-            f"qniht_batch_sharded needs a 1-D ('{BATCH_AXIS}',) mesh, got axes "
-            f"{mesh.axis_names}; build one with repro.parallel.batch.make_batch_mesh")
-    Y_pad, b = pad_batch(Y, mesh.devices.size)
-    res = _sharded_call(phi, Y_pad, key, mesh=mesh, **statics)
-    if Y_pad.shape[0] == b:
-        return res
-    return IHTResult(
-        x=res.x[:b],
-        trace=jax.tree_util.tree_map(lambda t: t[:, :b], res.trace),
-        iters_run=res.iters_run[:b],
-    )
 
 
 # SolverState sharding: every per-row leaf splits by rows (trace second axis),
@@ -310,16 +243,52 @@ def state_shardings(mesh: Mesh) -> SolverState:
         is_leaf=lambda x: isinstance(x, P))
 
 
-@partial(jax.jit, static_argnames=("mesh",) + _SEG_STATIC)
-def _sharded_segment_call(phi, state, *, mesh, n_steps, **statics):
-    fn = jax.shard_map(
+def _batch_mesh(mesh, n_devices, caller: str) -> Mesh:
+    """``mesh``, or one over ``n_devices`` local devices; must be 1-D ``batch``."""
+    mesh = mesh if mesh is not None else make_batch_mesh(n_devices)
+    if set(mesh.axis_names) != {BATCH_AXIS}:
+        raise ValueError(
+            f"{caller} needs a 1-D ('{BATCH_AXIS}',) mesh, got axes "
+            f"{mesh.axis_names}; build one with repro.parallel.batch.make_batch_mesh")
+    return mesh
+
+
+def _sharded_loop(phi, state, *, mesh, n_steps, **statics):
+    """The solver loop with ``state``'s rows split over ``mesh``: the new
+    state and each row's ``k_stop``, its shard's own (see ``_segment_core``)."""
+    return jax.shard_map(
         lambda phi_, st: _segment_core(phi_, st, n_steps=n_steps, **statics),
         mesh=mesh,
         in_specs=(P(), _SEG_SPECS),
-        out_specs=_SEG_SPECS,
+        out_specs=(_SEG_SPECS, P(BATCH_AXIS)),
         check_vma=False,
-    )
-    return fn(phi, state)
+    )(phi, state)
+
+
+@partial(jax.jit, static_argnames=("mesh",) + _STATIC)
+def _sharded_solve(phi, Y, key, *, mesh, n_iters, **statics) -> IHTResult:
+    state, b = pad_state(solver_init(phi, Y, n_iters=n_iters, key=key, **statics),
+                         mesh.devices.size)
+    state, iters = _sharded_loop(phi, state, mesh=mesh, n_steps=n_iters, **statics)
+    return solver_result(strip_state(state, b))._replace(iters_run=iters[:b])
+
+
+@partial(jax.jit, static_argnames=("mesh",) + _SEG_STATIC)
+def _sharded_segment_call(phi, state, *, mesh, n_steps, **statics):
+    return _sharded_loop(phi, state, mesh=mesh, n_steps=n_steps, **statics)[0]
+
+
+def sharded_qniht_run(phi, Y, key, *, mesh=None, n_devices=None, **statics) -> IHTResult:
+    """Solve the (B, M) chunk ``Y`` with its rows split over a ``("batch",)``
+    mesh: one program that builds the solver state, pads its rows to the
+    mesh width, runs the loop for all ``n_iters`` and strips the padding.
+    ``iters_run`` is the trip count of the shard that solved each row.
+
+    The backend of :func:`repro.core.niht.qniht_batch_sharded` and
+    :meth:`BatchServer.submit`; call those instead.
+    """
+    mesh = _batch_mesh(mesh, n_devices, "qniht_batch_sharded")
+    return _sharded_solve(phi, Y, key, mesh=mesh, **statics)
 
 
 def sharded_segment_run(phi, state: SolverState, n_steps: int, *, mesh=None,
@@ -338,11 +307,7 @@ def sharded_segment_run(phi, state: SolverState, n_steps: int, *, mesh=None,
     """
     if n_steps < 1:
         raise ValueError(f"n_steps must be >= 1, got {n_steps}")
-    mesh = mesh if mesh is not None else make_batch_mesh(n_devices)
-    if set(mesh.axis_names) != {BATCH_AXIS}:
-        raise ValueError(
-            f"sharded_segment_run needs a 1-D ('{BATCH_AXIS}',) mesh, got axes "
-            f"{mesh.axis_names}; build one with repro.parallel.batch.make_batch_mesh")
+    mesh = _batch_mesh(mesh, n_devices, "sharded_segment_run")
     statics = {**_SEG_DEFAULTS, **statics}
     _validate(phi, statics["bits_phi"], statics["bits_y"], state.key,
               statics["requantize"], statics["backend"], statics["threshold"],
@@ -403,11 +368,11 @@ class BatchServer:
                  with_trace: bool = False,
                  scale_granularity: str = "per_tensor",
                  group_size: Optional[int] = None, early_exit: bool = True,
-                 exit_tol: float = 0.0, unroll: int = 1,
+                 exit_tol: float = 0.0,
                  journal_dir: Optional[str] = None, resume: bool = False):
         _validate(phi, bits_phi, bits_y, key, requantize, backend, threshold,
                   real_signal, scale_granularity, group_size, early_exit,
-                  exit_tol, unroll)
+                  exit_tol)
         if resume and journal_dir is None:
             raise ValueError("resume=True needs a journal_dir to resume from")
         self.mesh = mesh if mesh is not None else make_batch_mesh(n_devices)
@@ -425,7 +390,7 @@ class BatchServer:
             shrink_k=shrink_k, max_backtracks=max_backtracks,
             real_signal=real_signal, nonneg=nonneg, with_trace=with_trace,
             scale_granularity=scale_granularity, group_size=group_size,
-            early_exit=early_exit, exit_tol=exit_tol, unroll=unroll,
+            early_exit=early_exit, exit_tol=exit_tol,
         )
         if backend == "packed":
             # Pack once with the exact key the in-loop pack would fold, then

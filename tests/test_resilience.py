@@ -52,6 +52,12 @@ CONFIGS = {
     "pair": dict(bits_phi=4, bits_y=8, requantize="pair"),
     "packed": dict(bits_phi=4, bits_y=8, requantize="fixed", backend="packed"),
     "freeze": dict(early_exit=True, exit_tol=1e-5),
+    "lossless": dict(early_exit=True),
+    # what every benchmark cell serves: packed 8-bit codes, lossless early
+    # exit, real non-negative signal, no residual trace
+    "served": dict(bits_phi=8, bits_y=8, requantize="fixed", backend="packed",
+                   early_exit=True, real_signal=True, nonneg=True,
+                   with_trace=False),
 }
 
 

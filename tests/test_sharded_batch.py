@@ -18,7 +18,7 @@ import numpy as np
 import pytest
 
 from repro.core import qniht_batch, qniht_batch_sharded
-from repro.parallel import BatchServer, make_batch_mesh, pad_batch
+from repro.parallel import BatchServer, make_batch_mesh
 from repro.sensing import make_gaussian_problem
 
 
@@ -54,17 +54,6 @@ class TestEarlyExit:
         r1 = qniht_batch(phi, Y, 6, 30, early_exit=True, **kw)
         assert bool(jnp.all(r0.x == r1.x))
 
-    def test_unroll_invariant_and_exclusive_with_early_exit(self):
-        """unroll is a compilation knob on the fixed-trip scan: identical
-        numerics at any value — and rejected with early_exit, whose while_loop
-        trip count is data-dependent and cannot unroll."""
-        phi, Y = _gaussian_batch()
-        r1 = qniht_batch(phi, Y, 6, 40)
-        r4 = qniht_batch(phi, Y, 6, 40, unroll=4)
-        assert bool(jnp.all(r1.x == r4.x))
-        with pytest.raises(ValueError, match="unroll"):
-            qniht_batch(phi, Y, 6, 40, early_exit=True, unroll=4)
-
     def test_freeze_rule_preserves_recovery_quality(self):
         """The freeze rule is a heuristic (a row on a long saddle plateau may
         freeze short of a late support escape), so the guarantee is quality:
@@ -96,22 +85,6 @@ class TestEarlyExit:
                         requantize="pair", early_exit=True)
         with pytest.raises(ValueError, match="exit_tol"):
             qniht_batch(phi, Y, 6, 5, exit_tol=1e-5)
-        with pytest.raises(ValueError, match="unroll"):
-            qniht_batch(phi, Y, 6, 5, unroll=0)
-
-
-class TestPadBatch:
-    def test_no_pad_when_divisible(self):
-        Y = jnp.ones((8, 3))
-        Yp, b = pad_batch(Y, 4)
-        assert Yp.shape == (8, 3) and b == 8
-
-    def test_pads_with_zero_rows(self):
-        Y = jnp.ones((5, 3))
-        Yp, b = pad_batch(Y, 4)
-        assert Yp.shape == (8, 3) and b == 5
-        assert bool(jnp.all(Yp[5:] == 0.0))
-        assert bool(jnp.all(Yp[:5] == 1.0))
 
 
 class TestShardedSingleDeviceMesh:

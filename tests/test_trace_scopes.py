@@ -177,7 +177,7 @@ own = [int(qniht_batch(base.phi, rows, early_exit=True, requantize="fixed",
 statics = dict(kw, bits_phi=None, bits_y=None, requantize="fixed", backend="dense",
                threshold="topk", c=0.01, shrink_k=2.0, max_backtracks=30,
                real_signal=False, nonneg=False, scale_granularity="per_tensor",
-               group_size=None, early_exit=True, exit_tol=0.0, unroll=1)
+               group_size=None, early_exit=True, exit_tol=0.0)
 res = sharded_qniht_run(base.phi, Y, None, mesh=make_batch_mesh(2), **statics)
 got = np.asarray(res.iters_run).tolist()
 assert got == [own[0]] * 3 + [own[1]] * 2, (got, own)
