@@ -18,7 +18,9 @@ representations of Φ̂:
 * :class:`SubsampledFourierOperator` — *matrix-free* Φ: an implicit 2D FFT
   followed by a k-space sampling mask (the MRI workload, paper §5's brain
   images). No (M, N) array ever exists — at 256×256 the dense partial-Fourier
-  matrix would be ~2 GB; the implicit form stores only the sample indices.
+  matrix would be ~2 GB; the implicit form stores only the sample indices and
+  their inverse map, so both the sampling (``mv``) and the zero-fill (``rmv``)
+  are gathers.
 * :class:`WaveletSynthesisOperator` — the orthonormal synthesis W† mapping
   wavelet coefficients to image pixels (implicit multi-level DWT, see
   :mod:`repro.transforms.wavelet`).
@@ -261,19 +263,28 @@ class SubsampledFourierOperator:
     the k-space positions Ω (the MRI acquisition model, paper §5).
 
     ``mv`` is ``fft2(norm="ortho")`` + gather at the flat sample indices;
-    ``rmv`` is the exact adjoint: zero-fill scatter + ``ifft2(norm="ortho")``
-    (F is unitary, so (P_Ω F)† = F† P_Ωᵀ). Nothing of size M×N is ever built —
-    ``nbytes`` counts only the stored sampling pattern (int32 indices + the
-    1-bit/pixel mask an acquisition system would keep), which is why a 256×256
-    problem (dense Φ ≈ 2 GB complex64) costs ~100 KB here.
+    ``rmv`` is the exact adjoint (F is unitary, so (P_Ω F)† = F† P_Ωᵀ): the
+    zero-fill P_Ωᵀ is a gather too, of ``v`` extended by one zero slot, through
+    the inverse map (grid position → its place in ``indices``, or the zero
+    slot), then ``ifft2(norm="ortho")``. It equals a zero-fill scatter bit for
+    bit. Nothing of size M×N is ever built — ``nbytes`` counts only the stored
+    sampling pattern (int32 indices, the 1-bit/pixel mask an acquisition
+    system would keep, and the int32 inverse map), which is why a 256×256
+    problem (dense Φ ≈ 2 GB complex64) costs ~360 KB here.
 
     Build from a boolean k-space mask with :meth:`from_mask` (concrete, outside
-    jit — the sample count M becomes the static output shape).
+    jit — the sample count M becomes the static output shape). The constructor
+    builds the inverse map on the host from the concrete ``indices``.
     """
 
     def __init__(self, indices: jax.Array, resolution: int):
         self.indices = indices          # (M,) int32, flat positions in the r×r grid
         self.resolution = int(resolution)
+        # each grid position's place in `indices`, or M: the zero slot rmv appends
+        idx = np.asarray(indices)
+        inv = np.full(self.resolution**2, idx.shape[0], np.int32)
+        inv[idx] = np.arange(idx.shape[0], dtype=np.int32)
+        self.inverse = jnp.asarray(inv, jnp.int32)  # (r²,)
 
     @classmethod
     def from_mask(cls, mask) -> "SubsampledFourierOperator":
@@ -295,7 +306,9 @@ class SubsampledFourierOperator:
     @property
     def nbytes(self) -> int:
         # sampling pattern only: int32 sample indices + the packed boolean mask
-        return self.indices.shape[0] * 4 + math.ceil(self.resolution**2 / 8)
+        # + the int32 inverse map the zero-fill gathers through
+        n = self.resolution**2
+        return self.indices.shape[0] * 4 + math.ceil(n / 8) + n * 4
 
     def mask(self) -> jax.Array:
         """(r, r) boolean k-space sampling mask (recomputed from the indices)."""
@@ -310,7 +323,7 @@ class SubsampledFourierOperator:
         return self
 
     # named scopes "fft" and "sample" label the transform and the k-space
-    # gather/scatter in a profiler trace (metadata only)
+    # gathers in a profiler trace (metadata only)
     def mv(self, x: jax.Array) -> jax.Array:
         r = self.resolution
         img = x.reshape(*x.shape[:-1], r, r)
@@ -322,18 +335,24 @@ class SubsampledFourierOperator:
     def rmv(self, v: jax.Array) -> jax.Array:
         r = self.resolution
         with jax.named_scope("sample"):
-            full = jnp.zeros((*v.shape[:-1], r * r), jnp.complex64)
-            full = full.at[..., self.indices].set(v.astype(self.dtype))
+            v = v.astype(self.dtype)
+            padded = jnp.concatenate([v, jnp.zeros((*v.shape[:-1], 1), self.dtype)], -1)
+            full = jnp.take(padded, self.inverse, axis=-1, mode="clip")
         with jax.named_scope("fft"):
             img = jnp.fft.ifft2(full.reshape(*v.shape[:-1], r, r), norm="ortho")
         return img.reshape(*v.shape[:-1], r * r).astype(self.dtype)
 
     def tree_flatten(self):
-        return (self.indices,), (self.resolution,)
+        return (self.indices, self.inverse), (self.resolution,)
 
     @classmethod
     def tree_unflatten(cls, aux, children):
-        return cls(children[0], aux[0])
+        # past __init__, which builds the map from concrete indices:
+        # transformations unflatten with placeholder leaves, None among them
+        obj = object.__new__(cls)
+        obj.indices, obj.inverse = children
+        obj.resolution = aux[0]
+        return obj
 
 
 @jax.tree_util.register_pytree_node_class

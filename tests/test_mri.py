@@ -3,6 +3,8 @@
 Covers:
 * adjointness ⟨Φx, r⟩ == ⟨x, Φ†r⟩ for real and complex inputs (F unitary ⇒ the
   zero-fill/IFFT adjoint is exact, not approximate),
+* the zero-fill gather through the inverse map equals a scatter bit for bit,
+  and the map survives pytree flattening and jit,
 * parity of the matrix-free operator vs an explicitly materialized partial-DFT
   Φ on small grids (mv, rmv, and full qniht iterates),
 * phantom/mask/observation substrate properties,
@@ -10,11 +12,14 @@ Covers:
   PSNR ≥ 30 dB without a dense Φ,
 * operator-input validation (bits_phi/backend rejected, 2-D y rejected).
 """
+from functools import partial
+
 import numpy as np
 import jax
 import jax.numpy as jnp
 import pytest
 
+from repro.configs.mri_brain import CONFIG as BRAIN
 from repro.core import (
     DenseOperator,
     SubsampledFourierOperator,
@@ -38,6 +43,37 @@ from repro.sensing import (
 def _small_op(r=16, frac=0.4, seed=0):
     mask = cartesian_mask(r, frac, jax.random.PRNGKey(seed))
     return SubsampledFourierOperator.from_mask(mask), mask
+
+
+def _one_point_mask(r, flat):
+    mask = np.zeros((r, r), bool)
+    mask.flat[flat] = True
+    return mask
+
+
+# k-space masks for the zero-fill check: the extremes, a small random one and
+# the 256² 35 % variable-density mask of configs/mri_brain
+_ZERO_FILL_MASKS = {
+    "single": lambda: _one_point_mask(8, 37),
+    "dc_only": lambda: _one_point_mask(8, 0),
+    "every_point": lambda: np.ones((8, 8), bool),
+    "random16": lambda: np.random.default_rng(4).random((16, 16)) < 0.4,
+    "brain256": lambda: cartesian_mask(
+        BRAIN.resolution, BRAIN.fraction, jax.random.split(jax.random.PRNGKey(BRAIN.seed), 3)[1],
+        BRAIN.density, BRAIN.center_fraction),
+}
+
+
+_jit_rmv = jax.jit(lambda op, v: op.rmv(v))
+_jit_identity = jax.jit(lambda op: op)
+
+
+@partial(jax.jit, static_argnames="r")
+def _scatter_rmv(idx, v, *, r):
+    """P_Ωᵀv written as the zero-fill scatter, then the orthonormal ifft2."""
+    full = jnp.zeros((*v.shape[:-1], r * r), v.dtype).at[..., idx].set(v)
+    img = jnp.fft.ifft2(full.reshape(*v.shape[:-1], r, r), norm="ortho")
+    return img.reshape(*v.shape[:-1], r * r)
 
 
 def _materialize(op):
@@ -119,7 +155,8 @@ class TestSubsampledFourierOperator:
     def test_nbytes_counts_pattern_only(self):
         op, mask = _small_op(r=16)
         m = int(np.asarray(mask).sum())
-        assert op.nbytes == m * 4 + (16 * 16 + 7) // 8
+        # int32 indices + 1-bit mask + the int32 inverse map of the zero-fill
+        assert op.nbytes == m * 4 + (16 * 16 + 7) // 8 + 16 * 16 * 4
         # the point: orders of magnitude below the dense complex64 Φ
         assert op.nbytes < m * 16 * 16 * 8 / 100
 
@@ -132,6 +169,50 @@ class TestSubsampledFourierOperator:
     def test_mask_round_trip(self):
         op, mask = _small_op(r=16, seed=7)
         np.testing.assert_array_equal(np.asarray(op.mask()), np.asarray(mask))
+
+    @pytest.mark.parametrize("batch", [None, 8], ids=["unbatched", "B8"])
+    @pytest.mark.parametrize("mask_name", sorted(_ZERO_FILL_MASKS))
+    def test_rmv_equals_scatter_zero_fill_bit_for_bit(self, mask_name, batch):
+        """The gather through the inverse map is the zero-fill scatter P_Ωᵀv,
+        bit for bit, followed by the same ifft2."""
+        mask = _ZERO_FILL_MASKS[mask_name]()
+        op = SubsampledFourierOperator.from_mask(mask)
+        r, m = op.resolution, op.shape[0]
+        lead = () if batch is None else (batch,)
+        key = jax.random.PRNGKey(6)
+        v = jax.lax.complex(jax.random.normal(key, (*lead, m)),
+                            jax.random.normal(jax.random.fold_in(key, 1), (*lead, m)))
+        got = np.asarray(_jit_rmv(op, v))
+        want = np.asarray(_scatter_rmv(op.indices, v, r=r))
+        assert got.shape == (*lead, r * r) and got.dtype == np.complex64
+        np.testing.assert_array_equal(got.view(np.uint32), want.view(np.uint32))
+
+    def test_pytree_and_jit_keep_the_inverse_map(self):
+        op, mask = _small_op(r=16, seed=9)
+        m = op.shape[0]
+        inv = np.asarray(op.inverse)
+        idx = np.asarray(op.indices)
+        # the map: each sampled position points at its place, the rest at slot M
+        np.testing.assert_array_equal(inv[idx], np.arange(m))
+        assert inv.dtype == np.int32 and inv.shape == (16 * 16,)
+        assert np.all(inv[~np.asarray(mask).ravel()] == m)
+        # indices alone build the same map on the host
+        np.testing.assert_array_equal(
+            np.asarray(SubsampledFourierOperator(op.indices, 16).inverse), inv)
+
+        leaves, treedef = jax.tree_util.tree_flatten(op)
+        assert len(leaves) == 2
+        back = jax.tree_util.tree_unflatten(treedef, leaves)
+        assert back.resolution == 16
+        np.testing.assert_array_equal(np.asarray(back.inverse), inv)
+
+        through = _jit_identity(op)
+        np.testing.assert_array_equal(np.asarray(through.inverse), inv)
+        np.testing.assert_array_equal(np.asarray(through.indices), idx)
+        re = jax.random.normal(jax.random.PRNGKey(8), (3, m))
+        v = jax.lax.complex(re, -re)
+        np.testing.assert_array_equal(np.asarray(_jit_rmv(op, v)).view(np.uint32),
+                                      np.asarray(op.rmv(v)).view(np.uint32))
 
 
 class TestMRISubstrate:
